@@ -62,6 +62,8 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="1-step import-rot guard (CI): no full suites")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.smoke:
         smoke()
         return

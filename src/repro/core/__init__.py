@@ -14,6 +14,7 @@ from repro.core.peer import Peer, DeviceProfile, PeerFailure, StageState, \
     T4, V100, A100
 from repro.core.swarm import SwarmRunner, SwarmConfig
 from repro.core.faults import synth_preemptible_trace, TraceEvent
+from repro.core.reference import reference_losses
 
 __all__ = [
     "Sim", "Sleep", "Event", "Resource", "DHT", "MicrobatchLedger",
@@ -21,5 +22,5 @@ __all__ = [
     "plan_migration", "optimal_assignment", "pipeline_throughput",
     "Migration", "Peer", "DeviceProfile", "PeerFailure", "StageState",
     "T4", "V100", "A100", "SwarmRunner", "SwarmConfig",
-    "synth_preemptible_trace", "TraceEvent",
+    "synth_preemptible_trace", "TraceEvent", "reference_losses",
 ]

@@ -25,27 +25,14 @@ from typing import Optional, Sequence, Union
 
 import jax
 
-from repro import _compat  # noqa: F401  (AxisType shim for older jax)
 
 AxisSpec = Union[None, str, Sequence[str]]
 
 
-def current_mesh() -> Optional[jax.sharding.Mesh]:
-    """The ambient ``with mesh:`` context's mesh, or None off-mesh."""
-    try:
-        from jax.interpreters import pxla
-        mesh = pxla.thread_resources.env.physical_mesh
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except (ImportError, AttributeError):
-        pass
-    try:  # newer jax: explicit-sharding world
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except AttributeError:
-        pass
-    return None
+def current_mesh() -> Optional[jax.sharding.AbstractMesh]:
+    """The ambient ``with jax.set_mesh(mesh):`` mesh, or None off-mesh."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def _names(spec: AxisSpec) -> tuple[str, ...]:

@@ -37,12 +37,12 @@ host-device mesh.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro import _compat  # noqa: F401  (AxisType shim for older jax)
 from repro.compression import codecs
 from repro.compression import quant8
 from repro.dist.constrain import constrain
@@ -53,16 +53,6 @@ from repro.models.stage_plan import StagePlan, get_stage_plan
 from repro.optim.adamw import Optimizer
 
 Tree = Any
-
-# The jax version this repo's XLA workarounds are valid below.  Two
-# shims are tied to the requirements.txt pin ``jax<0.5``:
-#   * :func:`_restack` — the XLA 0.4.x SPMD partitioner miscompiles a
-#     concatenate whose concat dim is sharded (see its docstring);
-#   * ``repro._compat.AxisType`` — jax < 0.5 lacks
-#     ``jax.sharding.AxisType`` / ``make_mesh(axis_types=...)``.
-# tests/test_pins.py fails the moment the pin (or the installed jax)
-# crosses this ceiling, flagging both for re-evaluation/removal.
-JAX_PIN_CEILING = (0, 5)
 
 
 def stage_periodic(cfg: ArchConfig, n_stages: int) -> bool:
@@ -96,28 +86,6 @@ def _period_runs(cfg: ArchConfig, n_stages: int) -> list[tuple[str, int]]:
     return list(get_stage_plan(cfg, n_stages).stages[0].runs)
 
 
-def restack(per_stage: list) -> jax.Array:
-    """Stack per-stage arrays along a new leading (pod-sharded) dim.
-
-    Written as zeros + ``.at[s].set`` instead of ``jnp.stack``: the XLA
-    0.4.x SPMD partitioner miscompiles a concatenate whose concat dim is
-    sharded (here: over ``pod``) — stage s > 0 silently computes with
-    corrupted weights, ~3e-2 loss error on the 2x2x2 equivalence mesh.
-    Static-index dynamic-update-slices partition correctly (verified by
-    the mixed-kind equivalence tests in tests/test_distribution.py, on
-    BOTH call sites: the GSPMD tick below and the span-program stage
-    scan of ``repro.runtime.stage_model.build_span_program``).
-    """
-    out = jnp.zeros((len(per_stage),) + per_stage[0].shape,
-                    per_stage[0].dtype)
-    for s, a in enumerate(per_stage):
-        out = out.at[s].set(a)
-    return out
-
-
-_restack = restack          # historical (pre-span-builder) private name
-
-
 def _stage_blocks(cfg: ArchConfig, blocks: Tree, n_stages: int) -> Tree:
     """Regroup ``params['blocks']`` (global layer stacks) into per-stage
     stacks: one tree per period run, leaves ``[n_stages, count, ...]``.
@@ -125,7 +93,7 @@ def _stage_blocks(cfg: ArchConfig, blocks: Tree, n_stages: int) -> Tree:
     Pure reshape for the common homogeneous cases.  For mixed-kind
     periodic patterns each (stage, period-run) segment is a contiguous
     same-kind layer range, so it sits inside exactly one maximal global
-    run: a static slice of that run's stack, restacked across stages
+    run: a static slice of that run's stack, stacked across stages
     (differentiable, so gradients land back on the original stacks).
     """
     if cfg.share_groups:
@@ -149,7 +117,7 @@ def _stage_blocks(cfg: ArchConfig, blocks: Tree, n_stages: int) -> Tree:
             lo = lo_g - starts[ri]
             stages.append(jax.tree.map(
                 lambda a, _lo=lo: a[_lo:_lo + c], blocks[ri]))
-        out.append(jax.tree.map(lambda *xs: restack(list(xs)), *stages))
+        out.append(jax.tree.map(lambda *xs: jnp.stack(xs), *stages))
         off += c
     return out
 
@@ -166,22 +134,30 @@ def make_block_core(cfg: ArchConfig, runs: list[tuple[str, int]],
 
     ``blocks_s`` is one stage's ``[tree-per-run]`` list (leaves stacked
     ``[count, ...]``); ``reps > 1`` re-applies each layer (ALBERT-style
-    sharing, paper §4.3).
+    sharing, paper §4.3).  ``remat`` checkpoints every layer
+    application, so the backward keeps one layer input per application
+    (a shared stack's 16 reps included) and recomputes the rest.
     """
     def block_fn(blocks_s: Tree, x: jax.Array, aux: jax.Array, positions):
         for (kind, _), seg in zip(runs, blocks_s):
-            apply_fn = REGISTRY[kind][1]
+            apply_fn = functools.partial(REGISTRY[kind][1], cfg)
+            if remat:
+                apply_fn = jax.checkpoint(
+                    apply_fn, policy=jax.checkpoint_policies.nothing_saveable)
 
             def body(carry, p_l, _apply=apply_fn):
-                x, aux = carry
-                for _ in range(reps):          # reps > 1: ALBERT sharing
-                    x, a = _apply(cfg, p_l, x, positions)
-                    aux = aux + a
-                return (x, aux), None
+                def rep(carry, _):
+                    x, aux = carry
+                    x, a = _apply(p_l, x, positions)
+                    return (x, aux + a), None
 
-            if remat:
-                body = jax.checkpoint(
-                    body, policy=jax.checkpoint_policies.nothing_saveable)
+                if reps == 1:
+                    return rep(carry, None)
+                # reps > 1: ALBERT sharing, as a scan so the shared
+                # layer's weight gradient folds into one carry per rep
+                # instead of every rep's contribution staying live
+                return jax.lax.scan(rep, carry, None, length=reps)
+
             (x, aux), _ = jax.lax.scan(body, (x, aux), seg)
         return x, aux
 
@@ -340,9 +316,9 @@ def make_pipeline_train_step(cfg: ArchConfig, optimizer: Optimizer,
             # the true microbatch-0 write at t == S-1 overwrites it, and the
             # scatter's transpose zeroes the dead cotangents.
             #
-            # Shift out[s] -> slot s+1 as a static-index update-slice (the
-            # same construction _restack uses; a roll of the full buffer
-            # would drag the dead last-stage output along for the ride).
+            # Shift out[s] -> slot s+1 as a static-index update-slice (a
+            # roll of the full buffer would drag the dead last-stage
+            # output along for the ride).
             wire = jnp.zeros((S_, mb, S, wdim), out.dtype)
             wire = wire.at[1:].set(encode(out[:S_ - 1]))
             aux_buf = jnp.roll(aux_out, 1, 0).at[0].set(0.0)

@@ -25,7 +25,6 @@ from typing import Any, Optional
 
 import jax
 
-from repro import _compat  # noqa: F401  (AxisType shim for older jax)
 from repro.dist.constrain import AxisSpec, resolve_spec
 from repro.models import params as P
 

@@ -11,9 +11,13 @@ The mirror kernel dequantizes + decodes on the receiving side.
 
 TPU mapping: rows = flattened (batch x seq) tokens, tiled at ROW_TILE;
 ``w_c``/``w_d`` ride along whole (c is small — the wire width), so the
-matmuls hit the MXU at [ROW_TILE, d] x [d, c].  Quantization blocks
-(``qb``) subdivide the trailing wire dim, matching
-``repro.kernels.boundary.ref`` bit-for-bit.
+matmuls hit the MXU at [ROW_TILE, d] x [d, c], accumulating in f32 (the
+MXU's only accumulator).  Quantization blocks (``qb``) subdivide the
+trailing wire dim, matching ``repro.kernels.boundary.ref`` bit-for-bit.
+A block of 64 is half a 128-lane vreg row, and the TPU compiler refuses
+to split the lane dim, so blocks (and maxout pools) are formed on the
+transposed tile, where they split the sublane dim instead: the tile
+rides as [c, ROW_TILE] while it is blocked.
 """
 from __future__ import annotations
 
@@ -43,23 +47,31 @@ def _ln32(x32: jax.Array) -> jax.Array:
     return (x32 - mu) * jax.lax.rsqrt(var + 1e-6)
 
 
+def _blocks_t(z32: jax.Array, qb: int) -> jax.Array:
+    """[rows, c] tile -> [c // qb, qb, rows]: each quantization block on
+    the sublanes of one slab (see the module docstring)."""
+    rows, c = z32.shape
+    return z32.T.reshape(c // qb, qb, rows)
+
+
+def _quant_blocks(blocks: jax.Array):
+    scale = jnp.max(jnp.abs(blocks), axis=1, keepdims=True)
+    q = jnp.clip(jnp.round(blocks / jnp.maximum(scale, 1e-12) * 127.0),
+                 -127, 127)
+    return q, scale
+
+
 def _qdq32(z32: jax.Array, qb: int) -> jax.Array:
     """In-register row-blocked int8 round trip on an f32 tile."""
     rows, c = z32.shape
-    blocks = z32.reshape(rows, c // qb, qb)
-    scale = jnp.max(jnp.abs(blocks), axis=-1, keepdims=True)
-    q = jnp.clip(jnp.round(blocks / jnp.maximum(scale, 1e-12) * 127.0),
-                 -127, 127)
-    return (q * scale / 127.0).reshape(rows, c)
+    q, scale = _quant_blocks(_blocks_t(z32, qb))
+    return (q * scale / 127.0).reshape(c, rows).T
 
 
 def _quant32(z32: jax.Array, qb: int):
     rows, c = z32.shape
-    blocks = z32.reshape(rows, c // qb, qb)
-    scale = jnp.max(jnp.abs(blocks), axis=-1, keepdims=True)
-    q = jnp.clip(jnp.round(blocks / jnp.maximum(scale, 1e-12) * 127.0),
-                 -127, 127)
-    return q.reshape(rows, c).astype(jnp.int8), scale[..., 0]
+    q, scale = _quant_blocks(_blocks_t(z32, qb))
+    return (q.reshape(c, rows).T.astype(jnp.int8), scale[:, 0, :].T)
 
 
 def _encode32(x, w_ref, *, mode, k):
@@ -68,11 +80,13 @@ def _encode32(x, w_ref, *, mode, k):
     dt = x.dtype
     z = _ln32(x.astype(jnp.float32)).astype(dt)
     if mode == "bottleneck":
-        z = jnp.dot(z, w_ref[...].astype(dt))
+        z = jnp.dot(z, w_ref[...].astype(dt),
+                    preferred_element_type=jnp.float32).astype(dt)
         z = _ln32(z.astype(jnp.float32)).astype(dt)
     else:                                        # maxout: param-free pool
         rows, d = z.shape
-        z = z.reshape(rows, d // k, k).max(-1)
+        z = (z.astype(jnp.float32).T.reshape(d // k, k, rows).max(1).T
+             .astype(dt))
     return z
 
 
@@ -80,7 +94,8 @@ def _decode32(z, w_ref, *, mode):
     dt = z.dtype
     if mode == "maxout":
         z = _ln32(z.astype(jnp.float32)).astype(dt)
-    return jnp.dot(z, w_ref[...].astype(dt))
+    return jnp.dot(z, w_ref[...].astype(dt),
+                   preferred_element_type=jnp.float32).astype(dt)
 
 
 # ----------------------------------------------------------- kernel bodies
@@ -121,22 +136,24 @@ def _decode_kernel(z_ref, w_ref, o_ref, *, mode):
 
 def _dequant_decode_kernel(q_ref, s_ref, w_ref, o_ref, *, mode, qb):
     rows, c = q_ref.shape
-    blocks = q_ref[...].astype(jnp.float32).reshape(rows, c // qb, qb)
-    z = (blocks * s_ref[...][..., None] / 127.0).reshape(rows, c)
+    blocks = _blocks_t(q_ref[...].astype(jnp.float32), qb)
+    z = (blocks * s_ref[...].T[:, None, :] / 127.0).reshape(c, rows).T
     z = z.astype(o_ref.dtype)
     o_ref[...] = _decode32(z, w_ref, mode=mode).astype(o_ref.dtype)
 
 
 # ------------------------------------------------------------- call plumbing
 def _rows_call(body, x2d, w, out_shapes, interpret):
-    """Tile the leading (rows) dim; any ``w`` rides along whole."""
+    """Tile the leading (rows) dim; any ``w`` rides along whole, already
+    in the activation dtype the kernel bodies multiply in (an f32
+    [4096, 1024] ``w_c`` alone would fill the 16 MiB of scoped VMEM)."""
     rows = x2d.shape[0]
     t = _row_tile(rows)
     in_specs = [pl.BlockSpec((t, x2d.shape[1]), lambda i: (i, 0))]
     args = [x2d]
     if w is not None:
         in_specs.append(pl.BlockSpec(w.shape, lambda i: (0, 0)))
-        args.append(w)
+        args.append(w.astype(x2d.dtype))
     single = not isinstance(out_shapes, (list, tuple))
     outs = [out_shapes] if single else list(out_shapes)
     out_specs = [pl.BlockSpec((t, o.shape[1]), lambda i: (i, 0))
@@ -250,5 +267,5 @@ def dequantize_decode(q: jax.Array, s: jax.Array, w: jax.Array, mode: str,
         out_specs=pl.BlockSpec((t, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), dtype),
         interpret=resolve_interpret(interpret),
-    )(q2d, s2d, w)
+    )(q2d, s2d, w.astype(dtype))
     return out.reshape(*q.shape[:-1], d)

@@ -74,8 +74,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
         o_ref[0] = (acc_sc[...]
                     / jnp.maximum(l_sc[...], 1e-30)).astype(o_ref.dtype)
         if lse_ref is not None:     # log-sum-exp residual for the backward
-            lse_ref[0] = (m_sc[...] + jnp.log(
-                jnp.maximum(l_sc[...], 1e-30)))[:, 0]
+            lse_ref[0] = m_sc[...] + jnp.log(jnp.maximum(l_sc[...], 1e-30))
 
 
 def _flash_fwd_kernel_lse(q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -126,10 +125,12 @@ def flash_attention_fwd(q, k, v, causal=True, window=0, scale=None,
     out_specs = pl.BlockSpec((1, bq, Dv), lambda h, qi, ki: (h, qi, 0))
     out_shape = jax.ShapeDtypeStruct((B * H, Sq + pq, Dv), v.dtype)
     if with_lse:
+        # lse rides as [B*H, Sq, 1]: a (bq, 1) block keeps the TPU's
+        # (8, 128) tiling rule (a trailing dim equal to the array's own)
         out_specs = [out_specs,
-                     pl.BlockSpec((1, bq), lambda h, qi, ki: (h, qi))]
+                     pl.BlockSpec((1, bq, 1), lambda h, qi, ki: (h, qi, 0))]
         out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((B * H, Sq + pq), jnp.float32)]
+                     jax.ShapeDtypeStruct((B * H, Sq + pq, 1), jnp.float32)]
 
     res = pl.pallas_call(
         kernel,

@@ -1,4 +1,8 @@
 import os
+# Compile-only on 512 forced host devices: pinned to the CPU before jax
+# starts, so neither this process nor its --jobs children ever take an
+# accelerator another process holds.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512")
 # ^ MUST precede any jax-importing module (device count locks on first init).
@@ -152,7 +156,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
     step, args, in_sh, donate, pipeline, out_sh = build_cell(
         cfg, shape, mesh, remat=remat, accum=accum, opt_bf16=opt_bf16,
         full_logits=full_logits, strategy=strategy)
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step, in_shardings=in_sh, out_shardings=out_sh,
                           donate_argnums=donate).lower(*args)
         t_lower = time.time() - t0
@@ -162,8 +166,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):     # jax < 0.5: one dict per program
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     colls = hlo_analysis.collective_bytes(hlo)
     record.update({

@@ -202,8 +202,6 @@ def layer_flop_probe(cfg, shape) -> dict:
 
 def _flops_of(lowered) -> float:
     c = lowered.compile().cost_analysis() or {}
-    if isinstance(c, (list, tuple)):        # jax < 0.5: one dict per program
-        c = c[0] if c else {}
     return float(c.get("flops", 0.0))
 
 

@@ -6,42 +6,7 @@ first jax initialization.
 """
 from __future__ import annotations
 
-import os
-
 import jax
-
-from repro import _compat  # noqa: F401  (AxisType shim for older jax)
-
-# XLA latency-hiding flags for the async tick on real accelerators: let
-# the scheduler move collectives (the boundary all-gathers, the ring
-# All-Reduce) behind stage compute — the hardware analogue of the sim's
-# in-flight Link transfers.  Spellings valid for the 0.4.x pin (older
-# --xla_gpu_enable_async_collectives was removed upstream).
-ASYNC_XLA_FLAGS = (
-    "--xla_gpu_enable_latency_hiding_scheduler=true",
-    "--xla_gpu_enable_pipelined_collectives=true",
-    "--xla_gpu_enable_while_loop_double_buffering=true",
-)
-
-
-def enable_async_xla_flags(force: bool = False) -> bool:
-    """Append the latency-hiding/async-collective flags to ``XLA_FLAGS``,
-    gated on ``REPRO_XLA_ASYNC=1`` (or ``force=True``) so plain imports
-    never change compiler behavior.  Must run before the first jax
-    initialization (same contract as the dry-run's flag handling);
-    already-present flags are left alone.  Returns whether the env var
-    now carries all async flags."""
-    gate = os.environ.get("REPRO_XLA_ASYNC", "0").lower()
-    if not force and gate not in ("1", "true", "yes"):
-        return False
-    current = os.environ.get("XLA_FLAGS", "")
-    have = current.split()
-    missing = [f for f in ASYNC_XLA_FLAGS
-               if f.split("=")[0] not in
-               {h.split("=")[0] for h in have}]
-    if missing:
-        os.environ["XLA_FLAGS"] = " ".join(have + missing)
-    return True
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -23,6 +23,7 @@ from repro.data.synthetic import SyntheticLM
 from repro.optim import adamw, lamb, delayed_parameter_updates
 from repro.train.steps import make_train_step, make_state
 from repro.ckpt import save_checkpoint, restore_checkpoint, latest_step
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
@@ -43,6 +44,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     opt = (adamw(lr=args.lr) if args.optimizer == "adamw"

@@ -40,11 +40,25 @@ class ParamSpec:
                 f"axes {self.axes} do not match shape {self.shape}")
 
 
-def _fan_in(shape: Sequence[int]) -> int:
-    # For stacked-layer weights [L, in, out] the fan-in is the middle dim.
-    if len(shape) >= 2:
-        return shape[-2]
-    return max(1, shape[-1])
+_HEAD_AXES = ("heads", "kv_heads")
+
+
+def _fan_in(shape: Sequence[int], axes: Sequence[str] = ()) -> int:
+    """Width a weight's input contracts over.  Plain (and layer-stacked)
+    matrices ``[..., in, out]`` contract ``shape[-2]``.  Multi-head
+    projections carry a heads axis: ``[..., d, H, hd]`` contracts ``d``,
+    and ``[..., H, hd, d]`` back to the model width contracts ``H*hd``
+    (per-head recurrences ``[H, hd, out]`` stay at ``hd``)."""
+    if len(shape) < 2:
+        return max(1, shape[-1])
+    heads = [i for i, a in enumerate(axes) if a in _HEAD_AXES]
+    if heads and len(shape) >= 3:
+        i = heads[0]
+        if i == len(shape) - 2 and i > 0:
+            return shape[i - 1]
+        if i == len(shape) - 3 and axes[-1] == "embed":
+            return shape[i] * shape[i + 1]
+    return shape[-2]
 
 
 def _init_one(key: jax.Array, spec: ParamSpec) -> jax.Array:
@@ -56,7 +70,7 @@ def _init_one(key: jax.Array, spec: ParamSpec) -> jax.Array:
         std = 1.0 * spec.scale
         return (jax.random.normal(key, spec.shape, jnp.float32) * std).astype(spec.dtype)
     # normal / scaled: truncated-normal, std = scale / sqrt(fan_in)
-    std = spec.scale / math.sqrt(_fan_in(spec.shape))
+    std = spec.scale / math.sqrt(_fan_in(spec.shape, spec.axes))
     x = jax.random.truncated_normal(key, -2.0, 2.0, spec.shape, jnp.float32)
     return (x * std).astype(spec.dtype)
 

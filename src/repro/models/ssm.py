@@ -210,13 +210,17 @@ def apply_mlstm(cfg: ArchConfig, p: Tree, x: jax.Array,
         d_q = jnp.exp(csum + (m_in - m_new)[:, None])         # [B,c,H]
         y_inter = jnp.einsum("bihk,bhkv,bih->bihv", qb, C_in, d_q)
         # intra-chunk: score_ij = q_i k_j exp(csum_i - csum_j + li_j - m_new)
-        gk = jnp.exp(li - csum - m_new[:, None])              # [B,c,H]
         s = jnp.einsum("bihk,bjhk->bhij", qb, kb)
-        # d_ij = exp(csum_i - csum_j + li_j - m_new) = exp(csum_i) * gk_j, j<=i
-        dmat = jnp.exp(csum).transpose(0, 2, 1)[:, :, :, None] \
-            * gk.transpose(0, 2, 1)[:, :, None, :]            # [B,H,i,j]
+        # the exponent is formed whole and masked before exp: factored as
+        # exp(csum_i) * exp(li_j - csum_j - m_new), a 128-step chunk of
+        # forget gates near 1/2 puts the two factors at e**-88 and e**88,
+        # which flush to zero and overflow to inf (0 * inf = nan)
+        cs = csum.transpose(0, 2, 1)                          # [B,H,c]
+        lk = (li - csum).transpose(0, 2, 1) - m_new[:, :, None]
         mask = jnp.tril(jnp.ones((c, c), bool))
-        s = jnp.where(mask, s * dmat, 0.0)
+        dmat = jnp.exp(jnp.where(mask, cs[..., :, None] + lk[..., None, :],
+                                 -jnp.inf))                   # [B,H,i,j]
+        s = s * dmat
         y_intra = jnp.einsum("bhij,bjhv->bihv", s.astype(cd), vb)
         y = y_inter.astype(jnp.float32) + y_intra.astype(jnp.float32)
         # state update: C' = exp(total + m_in - m_new) C_in + sum_j gk'_j k_j v_j

@@ -6,7 +6,7 @@ reaches the same conclusion for preemptible fleets by fusing consecutive
 pipeline stages on one worker and re-partitioning on membership change.
 This backend is that lever: one peer serves stages ``[lo, hi)`` in a
 SINGLE jitted step (:class:`repro.runtime.stage_model.SpanProgram`,
-which reuses the ``repro.dist`` stage core and restack/stage-scan
+which reuses the ``repro.dist`` stage core and stack/stage-scan
 machinery), so
 
 * intra-span boundaries stay on-device — under a learned codec the

@@ -23,9 +23,8 @@ stages into ONE jitted fwd/bwd (the
 intra-span boundaries never leave the device: chaining stage ``b``'s
 in-program compress with stage ``b+1``'s decompress reproduces the exact
 single-stage math, minus the host crossing.  Structurally identical
-consecutive stages are stacked with :func:`repro.dist.pipeline.restack`
-(the XLA-0.4.x sharded-concat workaround — the same construction the
-GSPMD shifting buffer vmaps over ``pod``) and scanned over the stage dim;
+consecutive stages are stacked along a leading stage dim (the layout
+the GSPMD shifting buffer vmaps over ``pod``) and scanned over it;
 the per-stage layer math itself is
 :func:`repro.dist.pipeline.make_block_core`, shared with the compiled
 pipeline, so span peers, single-stage peers, and the GSPMD step compute
@@ -41,7 +40,7 @@ import jax.numpy as jnp
 
 from repro.compression import codecs
 from repro.dist.constrain import constrain
-from repro.dist.pipeline import make_block_core, restack
+from repro.dist.pipeline import make_block_core
 from repro.models.config import ArchConfig
 from repro.models.stage_plan import StagePlan, get_stage_plan
 from repro.models import params as P
@@ -172,7 +171,10 @@ def _make_stage_fwd(cfg: ArchConfig, s: int, n_stages: int, comp: str,
     core, emit the outbound wire tensor (hidden for the last stage — the
     head/loss is applied by the caller)."""
     _, runs, reps = _stage_runs(cfg, s, n_stages)
-    core = make_block_core(cfg, runs, reps)
+    # per-layer remat: a full-width stage's saved activations (11.3 GB
+    # of backward temporaries for a swarm-1b stage at 4 x 1024 tokens)
+    # would not fit one 16 GB chip beside its params and Adam state
+    core = make_block_core(cfg, runs, reps, remat=True)
     is_first, is_last = s == 0, s == n_stages - 1
 
     def stage_fwd(params: Tree, inp):
@@ -363,7 +365,7 @@ def _build_span_encdec(cfg: ArchConfig, n_stages: int, seq_len: int,
             if count >= 2:
                 members = [ps[i] for i in range(start, start + count)]
                 stacked = jax.tree.map(
-                    lambda *xs: restack(list(xs)), *members)
+                    lambda *xs: jnp.stack(xs), *members)
                 stacked = jax.tree.map(
                     lambda a: constrain(a, "pod", *([None] * (a.ndim - 1))),
                     stacked)
@@ -527,10 +529,9 @@ def build_span_program(cfg: ArchConfig, n_stages: int, seq_len: int,
     receiving stage's decompress, reproducing the single-stage math
     exactly, with zero host bytes for the fused boundary.  Runs of
     structurally identical covered stages are stacked along a leading
-    stage dim with :func:`repro.dist.pipeline.restack` (constrained to
-    ``pod`` when a mesh is ambient — the same sharded stacking the GSPMD
-    tick uses, so the XLA-0.4.x concat workaround is load-bearing here
-    too) and executed as a ``lax.scan`` over stages.
+    stage dim (constrained to ``pod`` when a mesh is ambient — the same
+    sharded stacking the GSPMD tick uses) and executed as a ``lax.scan``
+    over stages.
     """
     lo, hi = span
     if not (0 <= lo < hi <= n_stages):
@@ -568,7 +569,7 @@ def build_span_program(cfg: ArchConfig, n_stages: int, seq_len: int,
                 members = [params_by_stage[i]
                            for i in range(start, start + count)]
                 stacked = jax.tree.map(
-                    lambda *xs: restack(list(xs)), *members)
+                    lambda *xs: jnp.stack(xs), *members)
                 stacked = jax.tree.map(
                     lambda a: constrain(a, "pod", *([None] * (a.ndim - 1))),
                     stacked)

@@ -59,49 +59,3 @@ def tiny_dense_config(**kw):
                 param_dtype="float32")
     base.update(kw)
     return ArchConfig(**base)
-
-
-def reference_losses(cfg, programs, opt, seed, steps, seq, mb, gb,
-                     data_seed=17):
-    """Fault-free sequential single-stage-per-peer reference trajectory
-    (same data order, same params init) — the oracle every churn-/
-    runtime-/span-equivalence test compares a SwarmRunner against.  One
-    copy: the accumulation and token-weighted averaging conventions here
-    must stay in lockstep with ``SwarmRunner._all_reduce_and_step``."""
-    import jax
-    import jax.numpy as jnp
-    from repro.data.synthetic import SyntheticLM
-    from repro.runtime import init_stage_params
-
-    S = len(programs)
-    assert S >= 2
-    params = init_stage_params(programs, jax.random.PRNGKey(seed))
-    opt_states = [opt.init(p) for p in params]
-    ds = SyntheticLM(cfg.vocab_size, seq, mb, seed=data_seed)
-    idx, losses = 0, []
-    for _ in range(steps):
-        grads = [jax.tree.map(jnp.zeros_like, p) for p in params]
-        loss_sum, tok = 0.0, 0
-        for _ in range(gb // mb):
-            b = ds.batch(idx)
-            idx += 1
-            xs = [b["tokens"]]              # per-stage boundary inputs
-            for s in range(S - 1):
-                xs.append(programs[s].fwd(params[s], xs[-1]))
-            loss, gx, gp = programs[S - 1].bwd(params[S - 1], xs[-1],
-                                               b["labels"])
-            grads[S - 1] = jax.tree.map(jnp.add, grads[S - 1], gp)
-            for s in range(S - 2, 0, -1):
-                gx, gp = programs[s].bwd(params[s], xs[s], gx)
-                grads[s] = jax.tree.map(jnp.add, grads[s], gp)
-            _, gp = programs[0].bwd(params[0], xs[0], gx)
-            grads[0] = jax.tree.map(jnp.add, grads[0], gp)
-            loss_sum += float(loss)
-            tok += mb * seq
-        losses.append(loss_sum / tok)
-        for s in range(S):
-            gm = jax.tree.map(lambda g: g / tok, grads[s])
-            upd, opt_states[s] = opt.update(gm, opt_states[s], params[s])
-            params[s] = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
-                                     params[s], upd)
-    return losses

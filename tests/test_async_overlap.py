@@ -22,10 +22,6 @@ The load-bearing properties:
 * **overlap never loses** — the rebalancer prices an overlapped edge at
   ``max(compute, wire)`` <= ``compute + wire`` serial.
 """
-import os
-import subprocess
-import sys
-import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -33,8 +29,9 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from conftest import reference_losses, tiny_dense_config
-from repro.core import SwarmRunner, SwarmConfig, TraceEvent
+from conftest import tiny_dense_config
+from repro.core import SwarmRunner, SwarmConfig, TraceEvent, \
+    reference_losses
 from repro.core.rebalance import pipeline_throughput
 from repro.launch.mesh import make_peer_mesh
 from repro.optim import adamw, delayed_parameter_updates
@@ -237,51 +234,6 @@ def test_mesh_span_in_mixed_swarm_equals_reference():
                            MB, GB)
     np.testing.assert_allclose(m["loss"], ref, atol=2e-4)
     _assert_exactly_once(runner, 2, GB // MB)
-
-
-# ------------------------------------------------- XLA flags smoke
-_XLA_SMOKE = textwrap.dedent("""
-    import os, sys
-    sys.path.insert(0, "src")
-    os.environ["REPRO_XLA_ASYNC"] = "1"
-    from repro.launch.mesh import ASYNC_XLA_FLAGS, enable_async_xla_flags
-    assert enable_async_xla_flags()
-    flags = os.environ["XLA_FLAGS"].split()
-    assert all(f in flags for f in ASYNC_XLA_FLAGS), flags
-    # idempotent: a second call appends nothing
-    enable_async_xla_flags()
-    assert os.environ["XLA_FLAGS"].split() == flags
-    # jax still initializes and compiles with the flags set
-    import jax, jax.numpy as jnp
-    y = jax.jit(lambda x: (x * 2).sum())(jnp.arange(8.0))
-    assert float(y) == 56.0
-    print("XLA_ASYNC_SMOKE_OK")
-""")
-
-
-def test_async_xla_flags_gate_off_by_default():
-    env_gate = os.environ.pop("REPRO_XLA_ASYNC", None)
-    try:
-        from repro.launch.mesh import enable_async_xla_flags
-        before = os.environ.get("XLA_FLAGS")
-        assert not enable_async_xla_flags()
-        assert os.environ.get("XLA_FLAGS") == before
-    finally:
-        if env_gate is not None:
-            os.environ["REPRO_XLA_ASYNC"] = env_gate
-
-
-def test_async_xla_flags_import_and_compile_smoke():
-    """Subprocess (flags must precede the first jax init): gate on,
-    merge flags, then import jax and jit through them."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)
-    r = subprocess.run([sys.executable, "-c", _XLA_SMOKE],
-                       capture_output=True, text=True, env=env,
-                       cwd=os.path.join(os.path.dirname(__file__), ".."),
-                       timeout=300)
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "XLA_ASYNC_SMOKE_OK" in r.stdout
 
 
 # ------------------------------------------------- rebalance pricing

@@ -15,8 +15,9 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from conftest import reference_losses, tiny_dense_config
-from repro.core import SwarmRunner, SwarmConfig, TraceEvent, MicrobatchLedger
+from conftest import tiny_dense_config
+from repro.core import SwarmRunner, SwarmConfig, TraceEvent, \
+    MicrobatchLedger, reference_losses
 from repro.core.faults import synth_preemptible_trace
 from repro.core.sim import Sleep
 from repro.runtime import build_stage_programs
@@ -190,7 +191,7 @@ def churn_setup():
 
 
 def _reference_losses(cfg, programs, opt, seed):
-    """Fault-free sequential twin (shared oracle in conftest)."""
+    """Fault-free sequential twin (repro.core.reference_losses)."""
     return reference_losses(cfg, programs, opt, seed, STEPS, SEQ, MB, GB)
 
 

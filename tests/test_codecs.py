@@ -201,7 +201,7 @@ _CODEC_PIPELINE = textwrap.dedent("""
                          axis_types=(jax.sharding.AxisType.Auto,) * 3)
     pipe_step = make_pipeline_train_step(cfg, grad_opt, n_stages=2,
                                          n_microbatches=4, remat=False)
-    with mesh:
+    with jax.set_mesh(mesh):
         out_state, m = jax.jit(pipe_step)(state, batch)
     print("ref", float(ref_loss), "pipe", float(m["loss"]))
     assert abs(float(ref_loss) - float(m["loss"])) < 1e-4

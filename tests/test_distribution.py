@@ -78,7 +78,7 @@ _PIPELINE_EQUIV = textwrap.dedent("""
     pipe_step = make_pipeline_train_step(cfg, grad_opt, n_stages=2,
                                          n_microbatches=4, remat=False,
                                          compress="none")
-    with mesh:
+    with jax.set_mesh(mesh):
         out_state, m = jax.jit(pipe_step)(state, batch)
     print("ref", float(ref_loss), "pipe", float(m["loss"]))
     assert abs(float(ref_loss) - float(m["loss"])) < 1e-4
@@ -115,7 +115,7 @@ _MIXED_EQUIV = textwrap.dedent("""
     from repro.data import make_batch
 
     # xlstm-style mixed-kind periodic stack: the per-stage params take the
-    # slice-and-restack path, which the homogeneous tests never touch
+    # slice-and-stack path, which the homogeneous tests never touch
     cfg = ArchConfig(name="tiny-x", family="ssm", n_layers=6, d_model=64,
                      n_heads=4, n_kv_heads=4, d_ff=0, vocab_size=256,
                      head_dim=16, rope="none", act="gelu", norm="layernorm",
@@ -135,7 +135,7 @@ _MIXED_EQUIV = textwrap.dedent("""
     pipe_step = make_pipeline_train_step(cfg, grad_opt, n_stages=2,
                                          n_microbatches=4, remat=False,
                                          compress="none")
-    with mesh:
+    with jax.set_mesh(mesh):
         out_state, m = jax.jit(pipe_step)(state, batch)
     print("ref", float(ref_loss), "pipe", float(m["loss"]))
     assert abs(float(ref_loss) - float(m["loss"])) < 1e-4
@@ -152,8 +152,9 @@ _MIXED_EQUIV = textwrap.dedent("""
 @pytest.mark.slow
 def test_pipeline_mixed_kind_equals_reference():
     """Mixed-kind periodic stacks (xlstm-style) must pipeline exactly too:
-    guards the per-stage slice-and-restack path against the XLA SPMD
-    sharded-concatenate miscompile (see dist/pipeline.py::_restack)."""
+    guards the per-stage slice-and-stack path, a concatenate sharded
+    along its concat dim (``pod``), which older XLA SPMD partitioners
+    miscompiled."""
     r = subprocess.run([sys.executable, "-c", _MIXED_EQUIV],
                        capture_output=True, text=True,
                        cwd=os.path.join(os.path.dirname(__file__), ".."),
@@ -174,9 +175,9 @@ _SPAN_MIXED_EQUIV = textwrap.dedent("""
 
     # mixed-kind periodic stack, 4 stages of (mlstm, slstm): the span
     # [1, 3) covers TWO structurally identical interior stages, so the
-    # span builder stacks their param trees with restack and scans over
-    # the stage dim — the exact sharded-concat pattern the XLA 0.4.x
-    # workaround guards (stacked leaves constrained over "pod")
+    # span builder stacks their param trees and scans over the stage
+    # dim — a concatenate sharded along its concat dim (stacked leaves
+    # constrained over "pod")
     cfg = ArchConfig(name="tiny-x", family="ssm", n_layers=8, d_model=64,
                      n_heads=4, n_kv_heads=4, d_ff=0, vocab_size=256,
                      head_dim=16, rope="none", act="gelu", norm="layernorm",
@@ -200,11 +201,12 @@ _SPAN_MIXED_EQUIV = textwrap.dedent("""
 
     mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
                          axis_types=(jax.sharding.AxisType.Auto,) * 3)
-    with mesh:
+    with jax.set_mesh(mesh):
         x3 = span.fwd(tuple(params[1:3]), x1)
         gx1, gp = span.bwd(tuple(params[1:3]), x1, gx3)
-    # the 0.4.x miscompile corrupts stage s > 0 of the stack at ~3e-2;
-    # legitimate whole-graph fusion noise sits at f32-ulp scale
+    # a miscompiled sharded concatenate corrupts stage s > 0 of the
+    # stack at ~3e-2; legitimate whole-graph fusion noise sits at f32-ulp
+    # scale
     np.testing.assert_allclose(np.asarray(x3), np.asarray(x3_ref),
                                atol=1e-4)
     np.testing.assert_allclose(np.asarray(gx1), np.asarray(gx1_ref),
@@ -219,12 +221,11 @@ _SPAN_MIXED_EQUIV = textwrap.dedent("""
 
 @pytest.mark.slow
 def test_span_program_mixed_kind_equals_reference():
-    """The span builder's restack-and-scan path (structurally identical
+    """The span builder's stack-and-scan path (structurally identical
     interior stages stacked over the leading dim, constrained to "pod")
     must match the chained single-stage programs on a mesh with a real
-    pod axis: guards the XLA SPMD sharded-concatenate miscompile on the
-    span path, the second call site of dist/pipeline.py::restack (see
-    tests/test_pins.py)."""
+    pod axis: guards the sharded-concatenate partitioning on the span
+    path, the second place per-stage params are stacked over "pod"."""
     r = subprocess.run([sys.executable, "-c", _SPAN_MIXED_EQUIV],
                        capture_output=True, text=True,
                        cwd=os.path.join(os.path.dirname(__file__), ".."),
@@ -256,7 +257,7 @@ _INT8_PIPELINE = textwrap.dedent("""
                          axis_types=(jax.sharding.AxisType.Auto,) * 3)
     step = make_pipeline_train_step(cfg, opt, 2, 4, remat=False,
                                     compress="int8")
-    with mesh:
+    with jax.set_mesh(mesh):
         _, m = jax.jit(step)(state, batch)
     d = abs(float(ref_m["loss"]) - float(m["loss"]))
     print("loss delta under int8 boundaries:", d)

@@ -22,8 +22,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import reference_losses, tiny_dense_config
-from repro.core import SwarmRunner, SwarmConfig, TraceEvent
+from conftest import tiny_dense_config
+from repro.core import SwarmRunner, SwarmConfig, TraceEvent, \
+    reference_losses
 from repro.models.config import ArchConfig, MoEConfig, SSMConfig
 from repro.models.stage_plan import get_stage_plan, make_stage_plan
 from repro.optim import adamw
@@ -170,7 +171,7 @@ def _whisper_batch(cfg, idx, b=W_MB, seq=W_SEQ):
 
 def _whisper_reference(cfg, programs, opt, seed, steps=W_STEPS,
                        seq=W_SEQ, mb=W_MB, gb=W_GB):
-    """conftest.reference_losses with whisper's tree-valued boundaries
+    """repro.core.reference_losses with whisper's tree-valued boundaries
     and audio+token data (same accumulation conventions)."""
     S = len(programs)
     params = init_stage_params(programs, jax.random.PRNGKey(seed))

@@ -147,3 +147,28 @@ def test_full_configs_param_counts():
     for arch, (lo, hi) in expected.items():
         n = F.total_params(get_config(arch))
         assert lo < n < hi, (arch, n)
+
+
+@pytest.mark.parametrize("forget_logit", [0.0, -1.0])
+def test_mlstm_full_chunk_stays_finite(forget_logit):
+    """A 128-step mLSTM chunk with forget gates at or below 1/2: the
+    intra-chunk decay spans e**-88 (e**-168 at logit -1), so it must be
+    formed as one exponent, not exp(csum_i) * exp(-csum_j) — those
+    factors flush to zero and overflow, and 0 * inf poisons the forward
+    and its gradient with nans."""
+    from repro.models.config import ArchConfig, SSMConfig
+    from repro.models.params import init
+    from repro.models.ssm import apply_mlstm, mlstm_specs
+    cfg = ArchConfig(name="mlstm-chunk", family="ssm", n_layers=1,
+                     d_model=64, n_heads=4, n_kv_heads=4, d_ff=0,
+                     vocab_size=256, head_dim=16, rope="none",
+                     ssm=SSMConfig(chunk=128), compute_dtype="float32",
+                     param_dtype="float32")
+    p = init(jax.random.PRNGKey(0), mlstm_specs(cfg))
+    p["b_if"] = p["b_if"].at[:, 1].set(forget_logit)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 256, 64))
+    y, grads = jax.value_and_grad(
+        lambda p, x: jnp.sum(apply_mlstm(cfg, p, x) ** 2))(p, x)
+    assert np.isfinite(float(y)) and float(y) > 0
+    for g in jax.tree.leaves(grads):
+        assert np.isfinite(np.asarray(g)).all()

@@ -9,8 +9,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import reference_losses, tiny_dense_config
-from repro.core import SwarmRunner, SwarmConfig, TraceEvent
+from conftest import tiny_dense_config
+from repro.core import SwarmRunner, SwarmConfig, TraceEvent, \
+    reference_losses
 from repro.optim import adamw
 from repro.runtime import build_stage_programs
 
@@ -169,17 +170,21 @@ def test_rmsnorm_train_matches_autodiff():
 def test_pipeline_train_step_pallas_matches_jnp(wire_quant):
     """One GSPMD pipelined train step, kernels="pallas" vs "jnp" at
     identical config/init/batch: loss within 1e-5, every gradient leaf
-    within 1e-5 of the jnp path's (scale-normalized; the grad-identity
-    optimizer makes the param delta the accumulated gradient, avoiding
-    adam's amplification of f32 ULPs), boundary codec grads nonzero
-    (the fused crossing ships on this path)."""
+    within 1e-5 of the jnp path's (scale-normalized), boundary codec
+    grads nonzero (the fused crossing ships on this path).  The
+    grad-capturing optimizer keeps the accumulated gradient itself in
+    its state: a param delta ``(p + g) - p`` would round ``g`` to one
+    ULP of ``p`` (2**-23 for the O(1) embedding rows, 1.6e-5 of their
+    gradient's scale), which is coarser than the bound under test."""
     from repro.data import make_batch
     from repro.dist.pipeline import make_pipeline_train_step
     from repro.optim.adamw import Optimizer
     from repro.train.steps import make_state
     cfg_j, cfg_p = _cfg_pair(wire_quant=wire_quant, **CODEC_KW)
-    grad_opt = Optimizer(init=lambda p: {"z": jnp.zeros(())},
-                         update=lambda g, s, p: (g, s))
+    grad_opt = Optimizer(
+        init=lambda p: {"g": jax.tree.map(jnp.zeros_like, p)},
+        update=lambda g, s, p: (jax.tree.map(jnp.zeros_like, g),
+                                {"g": g}))
     batch = make_batch(cfg_j.vocab_size, SEQ, GB)
     outs = {}
     for name, cfg in (("jnp", cfg_j), ("pallas", cfg_p)):
@@ -190,10 +195,9 @@ def test_pipeline_train_step_pallas_matches_jnp(wire_quant):
                                                 n_microbatches=4,
                                                 remat=False))
         new_state, m = step(state, batch)
-        delta = jax.tree.map(lambda a, b: a - b, new_state["params"],
-                             state["params"])
-        outs[name] = (float(m["loss"]), delta)
-        for kk, g in delta["boundary"].items():
+        grads = new_state["opt"]["g"]
+        outs[name] = (float(m["loss"]), grads)
+        for kk, g in grads["boundary"].items():
             assert float(jnp.max(jnp.abs(g))) > 0, kk
     assert abs(outs["pallas"][0] - outs["jnp"][0]) < 1e-5
     # wire_quant: a 1-ULP pre-rounding diff can flip an int8 code at an

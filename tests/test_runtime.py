@@ -35,8 +35,8 @@ def _codec_cfg():
 
 
 def _reference_losses(cfg, programs, opt, seed, steps=STEPS):
-    """Fault-free sequential twin (shared oracle in conftest)."""
-    from conftest import reference_losses
+    """Fault-free sequential twin (repro.core.reference_losses)."""
+    from repro.core import reference_losses
     return reference_losses(cfg, programs, opt, seed, steps, SEQ, MB, GB)
 
 
@@ -382,7 +382,7 @@ _MULTIDEV_MIXED = textwrap.dedent("""
     m = runner.run(until=1e6)
     assert runner.step == STEPS
 
-    from conftest import reference_losses
+    from repro.core import reference_losses
     losses = reference_losses(cfg, runner.programs, opt, 0, STEPS,
                               SEQ, MB, GB)
     assert max(losses) - min(losses) > 1e-3      # params actually move

@@ -23,8 +23,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import reference_losses, tiny_dense_config
-from repro.core import SwarmRunner, SwarmConfig, TraceEvent
+from conftest import tiny_dense_config
+from repro.core import SwarmRunner, SwarmConfig, TraceEvent, \
+    reference_losses
 from repro.core.sim import Sleep
 from repro.optim import adamw
 from repro.runtime import (PipelineExecutor, StageExecutor,
