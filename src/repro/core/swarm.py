@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.compression import codecs
 from repro.core.sim import Sim, Sleep, Spawn
 from repro.core.dht import DHT
@@ -268,7 +269,7 @@ class SwarmRunner:
         self.record_accumulation = record_accumulation
         self.ledger_log: list[tuple[str, int, int, int, int, str]] = []
         self.metrics: dict[str, list] = {
-            "loss": [], "step_time": [], "samples_done": [],
+            "loss": [], "step_time": [],
             "throughput_t": [], "throughput_v": [], "migrations": 0,
             "failures": 0, "joins": 0, "recomputed_microbatches": 0,
             "span_changes": 0,       # split/merge/resize events applied
@@ -696,10 +697,12 @@ class SwarmRunner:
         state adoptions defer until the window closes (see ``_migrate``
         / ``_download_state``).  A span peer is a member of every
         covered stage's group, with per-stage grads/tokens/install."""
-        plan = self._ar_plan()
+        with obs.span("swarm.barrier", step=self.step):
+            plan = self._ar_plan()
         for s, group, ar_time, new_params, new_opt in plan:
             yield Sleep(ar_time)
-            self._ar_install(s, group, new_params, new_opt)
+            with obs.span("swarm.barrier", step=self.step):
+                self._ar_install(s, group, new_params, new_opt)
         self.step += 1
         self._maybe_checkpoint()
 
@@ -707,11 +710,11 @@ class SwarmRunner:
         """Async-barrier variant: identical numerics, applied atomically
         at the barrier instant (no yields at all); returns the total
         All-Reduce time for the concurrent window."""
-        plan = self._ar_plan()
         total = 0.0
-        for s, group, ar_time, new_params, new_opt in plan:
-            total += ar_time
-            self._ar_install(s, group, new_params, new_opt)
+        with obs.span("swarm.barrier", step=self.step):
+            for s, group, ar_time, new_params, new_opt in self._ar_plan():
+                total += ar_time
+                self._ar_install(s, group, new_params, new_opt)
         self.step += 1
         self._maybe_checkpoint()
         return total

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.sim import Sim, Sleep
 from repro.core.peer import Peer, PeerFailure
 from repro.core.wiring import StochasticWiring
@@ -42,6 +43,18 @@ class Microbatch:
     size: int = 1               # sequences
     n_tokens: int = 0
     attempt: int = 1            # provenance: ledger dispatch attempt
+
+
+def _in_hop_span(kind: str, mb: Microbatch, stage: int, peer: Peer,
+                 thunk: Callable[[], Any]) -> Callable[[], Any]:
+    """``thunk`` run inside the ``repro.hop.<kind>`` span.  The span
+    wraps the hop's numeric work alone: it opens and closes where the
+    peer runs the thunk, never across a sim yield."""
+    def hop():
+        with obs.span("hop." + kind, mb=mb.index, stage=stage,
+                      peer=peer.id):
+            return thunk()
+    return hop
 
 
 @dataclasses.dataclass
@@ -188,6 +201,7 @@ class Trainer:
                         thunk = (lambda _p=peer, _i=inp:
                                  _p.executor.wire_fwd(
                                      _p.executor.run_fwd(_p.state, _i)))
+                    thunk = _in_hop_span("fwd", mb, s, peer, thunk)
                 else:
                     thunk = lambda: None
                 ct = swarm.compute_time(peer, "fwd", s, mb)
@@ -293,6 +307,8 @@ class Trainer:
                                                             dy=_dy)
                             self.swarm.accumulate(_p, gp, mb, None)
                             return _p.executor.wire_bwd(gx)
+                    thunk = _in_hop_span("bwd", mb, hop.span.start, peer,
+                                         thunk)
                 else:
                     def thunk(_p=peer):
                         self.swarm.accumulate(_p, None, mb, None)

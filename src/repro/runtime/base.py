@@ -41,11 +41,14 @@ all its peers resumes from the latest completed step instead of step 0
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Hashable, Iterable, Optional, Protocol, \
     runtime_checkable
 
 import jax
 import jax.numpy as jnp
+
+from repro import obs
 
 Tree = Any
 
@@ -403,25 +406,52 @@ def slot_install(view: StageState, name: str, key: Hashable,
     view.slot(name)[key] = jax.tree.map(jnp.asarray, value)
 
 
+def exec_span(method: Callable) -> Callable:
+    """Run an executor method inside the ``repro.exec.<method name>``
+    span (:mod:`repro.obs`).  Its stat is ``stage``: the call's
+    ``stage=`` keyword when given, else the executor's sole stage; a span
+    executor's whole-span calls carry ``lo`` and ``hi`` instead."""
+    name = "exec." + method.__name__
+
+    @functools.wraps(method)
+    def spanned(self, *args, **kwargs):
+        stage = kwargs.get("stage")
+        if stage is not None:
+            where = {"stage": stage}
+        elif len(self.stages) == 1:
+            where = {"stage": self.stage}
+        else:
+            where = {"lo": self.stages.start, "hi": self.stages.stop}
+        with obs.span(name, **where):
+            return method(self, *args, **kwargs)
+    return spanned
+
+
+def fold_grads(acc: Tree, g: Tree) -> Tree:
+    """``acc + g`` leaf by leaf, in the accumulator's dtype."""
+    return jax.tree.map(lambda a, b: a + b.astype(a.dtype), acc, g)
+
+
 # donated-accumulator fold shared by every backend: one jit object, jax
 # caches the compiled fold per (tree structure, shapes, shardings).
 # Donating arg 0 makes the add in-place — the old grad_acc buffer is
 # dead the moment it returns (StageState owns it exclusively).
-_accumulate = jax.jit(
-    lambda acc, g: jax.tree.map(lambda a, b: a + b.astype(a.dtype), acc, g),
-    donate_argnums=(0,))
+_accumulate = jax.jit(fold_grads, donate_argnums=(0,))
 
 
 def fold_into(state: StageState, gp: Optional[Tree],
-              loss: Optional[float], n_tokens: int) -> None:
+              loss: Optional[float], n_tokens: int, stage: int) -> None:
     """Default ``accumulate``: fold one microbatch gradient + bookkeeping
-    into ``state`` (identical for single-device and mesh backends — the
-    donated jit respects whatever placement the trees carry)."""
-    if gp is not None:
-        state.grad_acc = _accumulate(state.grad_acc, gp)
-    state.token_count += n_tokens
-    if loss is not None:
-        state.loss_sum += loss
+    into ``state``, pipeline stage ``stage``'s view (identical for
+    single-device and mesh backends — the donated jit respects whatever
+    placement the trees carry).  Runs in the ``repro.exec.accumulate``
+    span."""
+    with obs.span("exec.accumulate", stage=stage):
+        if gp is not None:
+            state.grad_acc = _accumulate(state.grad_acc, gp)
+        state.token_count += n_tokens
+        if loss is not None:
+            state.loss_sum += loss
 
 
 def single_stage(ex: StageExecutor, stage: Optional[int]) -> None:
@@ -459,18 +489,24 @@ def wire_fwd_codec(ex: StageExecutor, y: Tree) -> Tree:
     span-edge boundaries.  Learned codecs already emitted the c-dim wire
     tensor inside the stage program; ``none`` crosses raw; a span whose
     last covered stage is the pipeline's last emits a loss, not a
-    boundary — and fused (intra-span) boundaries never reach here."""
-    if ex.compress_mode == "int8" and ex.stages.stop < ex.n_stages:
-        return _int8_roundtrip_tree(y, ex.quant_block, _wire_use_kernel(ex))
-    return y
+    boundary — and fused (intra-span) boundaries never reach here.
+    Runs in the ``repro.wire.fwd`` span; ``stage`` is the sending
+    stage."""
+    with obs.span("wire.fwd", stage=ex.stages.stop - 1):
+        if ex.compress_mode == "int8" and ex.stages.stop < ex.n_stages:
+            return _int8_roundtrip_tree(y, ex.quant_block,
+                                        _wire_use_kernel(ex))
+        return y
 
 
 def wire_bwd_codec(ex: StageExecutor, gx: Optional[Tree]
                    ) -> Optional[Tree]:
     """Shared ``wire_bwd`` codec step: int8 quantizes the boundary
     cotangent (None when the span starts at stage 0 — nothing crosses
-    back)."""
-    if gx is not None and ex.compress_mode == "int8":
-        return _int8_roundtrip_tree(gx, ex.quant_block,
-                                    _wire_use_kernel(ex))
-    return gx
+    back).  Runs in the ``repro.wire.bwd`` span; ``stage`` is the
+    sending (entry) stage."""
+    with obs.span("wire.bwd", stage=ex.stage):
+        if gx is not None and ex.compress_mode == "int8":
+            return _int8_roundtrip_tree(gx, ex.quant_block,
+                                        _wire_use_kernel(ex))
+        return gx

@@ -38,9 +38,9 @@ from repro.dist.sharding import ShardingRules, DEFAULT_RULES, \
 from repro.models.config import ArchConfig
 from repro.models.stage_plan import get_stage_plan
 from repro.models import params as P
-from repro.runtime.base import StageState, fold_into, host_snapshot, \
-    install_snapshot, single_stage, slot_export, slot_install, \
-    wire_bwd_codec, wire_fwd_codec
+from repro.runtime.base import StageState, exec_span, fold_into, \
+    host_snapshot, install_snapshot, single_stage, slot_export, \
+    slot_install, wire_bwd_codec, wire_fwd_codec
 from repro.runtime.stage_model import _traced, init_stage_params
 from repro.runtime import numeric as numeric_rt
 
@@ -184,6 +184,7 @@ class MeshExecutor:
             "(ROADMAP) — serve spans on the numeric/pipeline backends")
 
     # ---------------------------------------------------------- execution
+    @exec_span
     def run_fwd(self, state: StageState, inp: Tree,
                 labels: Optional[jax.Array] = None) -> Tree:
         inp = self._place_batch(inp)
@@ -191,6 +192,7 @@ class MeshExecutor:
             return self._fwd_j(state.params, inp, self._place_batch(labels))
         return self._fwd_j(state.params, inp)
 
+    @exec_span
     def run_bwd(self, state: StageState, inp: Tree,
                 dy: Optional[Tree] = None,
                 labels: Optional[jax.Array] = None):
@@ -231,7 +233,7 @@ class MeshExecutor:
                    loss: Optional[float], n_tokens: int,
                    stage: Optional[int] = None) -> None:
         single_stage(self, stage)
-        fold_into(state, gp, loss, n_tokens)
+        fold_into(state, gp, loss, n_tokens, self.stage)
 
     def export_grads(self, state: StageState,
                      stage: Optional[int] = None) -> Tree:
@@ -244,6 +246,7 @@ class MeshExecutor:
         single_stage(self, stage)
         return jax.device_get(state.params), jax.device_get(state.opt)
 
+    @exec_span
     def adopt_step(self, state: StageState, new_params: Tree,
                    new_opt: Tree, stage: Optional[int] = None) -> None:
         single_stage(self, stage)
@@ -439,6 +442,7 @@ class MeshSpanExecutor:
             "(ROADMAP) — serve spans on the numeric/pipeline backends")
 
     # ---------------------------------------------------------- execution
+    @exec_span
     def run_fwd(self, state: StageState, inp: Tree,
                 labels: Optional[jax.Array] = None) -> Tree:
         ps = self._params_tuple(state)
@@ -447,6 +451,7 @@ class MeshSpanExecutor:
             return self._fwd_j(ps, inp, self._place_batch(labels))
         return self._fwd_j(ps, inp)
 
+    @exec_span
     def run_bwd(self, state: StageState, inp: Tree,
                 dy: Optional[Tree] = None,
                 labels: Optional[jax.Array] = None):
@@ -485,7 +490,7 @@ class MeshSpanExecutor:
                    loss: Optional[float], n_tokens: int,
                    stage: Optional[int] = None) -> None:
         s = self._require(stage)
-        fold_into(state.per_stage[s], gp, loss, n_tokens)
+        fold_into(state.per_stage[s], gp, loss, n_tokens, s)
 
     def export_grads(self, state: StageState,
                      stage: Optional[int] = None) -> Tree:
@@ -497,6 +502,7 @@ class MeshSpanExecutor:
         sub = state.per_stage[self._require(stage)]
         return jax.device_get(sub.params), jax.device_get(sub.opt)
 
+    @exec_span
     def adopt_step(self, state: StageState, new_params: Tree,
                    new_opt: Tree, stage: Optional[int] = None) -> None:
         s = self._require(stage)

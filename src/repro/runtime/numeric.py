@@ -7,8 +7,11 @@ runners, across the churn tests' seed matrix, across benchmark repeats —
 shares one jitted ``fwd``/``bwd`` per stage instead of re-tracing its
 own.  A retrace counter (a trace-time side effect inside the jitted
 body) records every actual XLA trace per ``(stage, kind, argument
-shapes)``; ``compile_stats()`` is what the fairness/retrace tests and
-``benchmarks/bench_swarm.py`` read.
+shapes)`` in the process-wide counter store of :mod:`repro.obs`;
+``compile_stats()`` is what the fairness/retrace tests and
+``benchmarks/bench_swarm.py`` read.  The executors' calls open the
+``repro.exec.*`` and ``repro.wire.*`` profiler spans (see
+:mod:`repro.obs`).
 
 Gradient accumulation donates the accumulator buffer (``grad_acc`` is
 exclusively owned by its :class:`StageState`), so the fold is in-place
@@ -22,11 +25,12 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.compression import codecs
 from repro.models.config import ArchConfig
-from repro.runtime.base import StageState, fold_into, host_snapshot, \
-    install_snapshot, single_stage, slot_export, slot_install, \
-    wire_bwd_codec, wire_fwd_codec
+from repro.runtime.base import StageState, exec_span, fold_into, \
+    host_snapshot, install_snapshot, single_stage, slot_export, \
+    slot_install, wire_bwd_codec, wire_fwd_codec
 from repro.models.stage_plan import get_stage_plan
 from repro.runtime.stage_model import (SpanProgram, StageProgram,
                                        build_span_program,
@@ -42,16 +46,16 @@ _PROGRAMS: dict[tuple, list[StageProgram]] = {}
 # (cfg, n_stages, seq_len, comp, (lo, hi)) -> SpanProgram: one fused jit
 # per (span, codec), shared by every peer serving that span
 _SPANS: dict[tuple, SpanProgram] = {}
-# (stage, kind, shapes) per program-cache key -> number of XLA traces
-_TRACES: dict[tuple, int] = {}
 _LOCK = threading.Lock()
+# counter-store keys of the XLA trace counts: (_TRACE, key)
+_TRACE = "xla_trace"
 
 
 def record_trace(key: tuple) -> None:
-    """Count one XLA trace under ``key`` — the single counter store for
-    every backend (numeric programs and mesh jits both report here)."""
-    with _LOCK:
-        _TRACES[key] = _TRACES.get(key, 0) + 1
+    """Count one XLA trace under ``key`` in the :mod:`repro.obs` store —
+    every backend (numeric programs, mesh jits, serving sessions)
+    reports here."""
+    obs.count((_TRACE, key))
 
 
 def reset_compile_stats() -> None:
@@ -60,8 +64,8 @@ def reset_compile_stats() -> None:
     that assert compile counts start from a genuinely cold cache."""
     import sys
     from repro.runtime import mesh as mesh_rt   # lazy: mesh imports us
+    obs.reset()
     with _LOCK:
-        _TRACES.clear()
         _PROGRAMS.clear()
         _SPANS.clear()
     with mesh_rt._LOCK:
@@ -72,13 +76,12 @@ def reset_compile_stats() -> None:
 
 
 def compile_stats() -> dict:
-    """``{"programs_cached", "traces", "per_key"}`` — ``traces`` is the
-    total number of XLA traces since the last reset; ``per_key`` maps
-    ``(cfg_name, n_stages, seq, comp, stage, kind, shapes)`` -> count."""
-    with _LOCK:
-        return {"programs_cached": len(_PROGRAMS),
-                "traces": sum(_TRACES.values()),
-                "per_key": dict(_TRACES)}
+    """``{"traces", "per_key"}`` — ``traces`` is the total number of XLA
+    traces since the last reset; ``per_key`` maps ``(cfg_name, n_stages,
+    seq, comp, stage, kind, shapes)`` -> count."""
+    per_key = {k[1]: v for k, v in obs.counters().items()
+               if isinstance(k, tuple) and k[0] == _TRACE}
+    return {"traces": sum(per_key.values()), "per_key": per_key}
 
 
 def get_stage_programs(cfg: ArchConfig, n_stages: int, seq_len: int,
@@ -191,12 +194,14 @@ class NumericExecutor:
             total_len, compress=self.compress_mode)
 
     # ---------------------------------------------------------- execution
+    @exec_span
     def run_fwd(self, state: StageState, inp: Tree,
                 labels: Optional[jax.Array] = None) -> Tree:
         if self.stage == self.n_stages - 1:
             return self.prog.fwd(state.params, inp, labels)
         return self.prog.fwd(state.params, inp)
 
+    @exec_span
     def run_bwd(self, state: StageState, inp: Tree,
                 dy: Optional[Tree] = None,
                 labels: Optional[jax.Array] = None):
@@ -233,7 +238,7 @@ class NumericExecutor:
                    loss: Optional[float], n_tokens: int,
                    stage: Optional[int] = None) -> None:
         single_stage(self, stage)
-        fold_into(state, gp, loss, n_tokens)
+        fold_into(state, gp, loss, n_tokens, self.stage)
 
     def export_grads(self, state: StageState,
                      stage: Optional[int] = None) -> Tree:
@@ -245,6 +250,7 @@ class NumericExecutor:
         single_stage(self, stage)
         return state.params, state.opt
 
+    @exec_span
     def adopt_step(self, state: StageState, new_params: Tree,
                    new_opt: Tree, stage: Optional[int] = None) -> None:
         single_stage(self, stage)

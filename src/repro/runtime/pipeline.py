@@ -41,9 +41,9 @@ import jax.numpy as jnp
 from repro.compression import codecs
 from repro.models.config import ArchConfig
 from repro.models import params as P
-from repro.runtime.base import StageState, fold_into, host_snapshot, \
-    install_snapshot, slot_export, slot_install, wire_bwd_codec, \
-    wire_fwd_codec
+from repro.runtime.base import StageState, exec_span, fold_into, \
+    host_snapshot, install_snapshot, slot_export, slot_install, \
+    wire_bwd_codec, wire_fwd_codec
 from repro.runtime import numeric as numeric_rt
 
 Tree = Any
@@ -131,6 +131,7 @@ class PipelineExecutor:
         return stage
 
     # ---------------------------------------------------------- execution
+    @exec_span
     def run_fwd(self, state: StageState, inp: Tree,
                 labels: Optional[jax.Array] = None) -> Tree:
         ps = self._params_tuple(state)
@@ -138,6 +139,7 @@ class PipelineExecutor:
             return self.prog.fwd(ps, inp, labels)
         return self.prog.fwd(ps, inp)
 
+    @exec_span
     def run_bwd(self, state: StageState, inp: Tree,
                 dy: Optional[Tree] = None,
                 labels: Optional[jax.Array] = None):
@@ -179,7 +181,7 @@ class PipelineExecutor:
                    loss: Optional[float], n_tokens: int,
                    stage: Optional[int] = None) -> None:
         s = self._require(stage)
-        fold_into(state.per_stage[s], gp, loss, n_tokens)
+        fold_into(state.per_stage[s], gp, loss, n_tokens, s)
 
     def export_grads(self, state: StageState,
                      stage: Optional[int] = None) -> Tree:
@@ -190,6 +192,7 @@ class PipelineExecutor:
         sub = state.per_stage[self._require(stage)]
         return sub.params, sub.opt
 
+    @exec_span
     def adopt_step(self, state: StageState, new_params: Tree,
                    new_opt: Tree, stage: Optional[int] = None) -> None:
         sub = state.per_stage[self._require(stage)]

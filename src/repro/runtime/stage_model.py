@@ -95,14 +95,16 @@ def _traced(fn: Callable, hook: Optional[Callable], stage, kind: str
     """Jit ``fn``; if ``hook`` is given, call it once per XLA trace (the
     body side effect runs at trace time only) with the argument shapes —
     the runtime layer's retrace counter hangs off this.  ``stage`` is an
-    int for single-stage programs, a ``(lo, hi)`` span tuple for spans."""
-    if hook is None:
-        return jax.jit(fn)
-
+    int for single-stage programs, a ``(lo, hi)`` span tuple for spans.
+    The program is named ``stage_<kind>`` or ``span_<kind>``
+    (``jit_stage_bwd`` in a profiler trace)."""
     def counted(*args):
-        hook(stage, kind, tuple(tuple(a.shape) for a in args
-                                if hasattr(a, "shape")))
+        if hook is not None:
+            hook(stage, kind, tuple(tuple(a.shape) for a in args
+                                    if hasattr(a, "shape")))
         return fn(*args)
+    counted.__name__ = counted.__qualname__ = (
+        ("span_" if isinstance(stage, tuple) else "stage_") + kind)
     return jax.jit(counted)
 
 

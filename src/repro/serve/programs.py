@@ -256,6 +256,7 @@ def build_session_program(cfg: ArchConfig, n_stages: int,
                        tuple(tuple(a.shape) for a in jax.tree.leaves(args)
                              if hasattr(a, "shape"))[:4])
             return fn(*args)
+        counted.__name__ = counted.__qualname__ = kind
         return jax.jit(counted)
 
     return SessionProgram(
@@ -323,6 +324,7 @@ def full_session_program(cfg: ArchConfig, total_len: int,
                        tuple(tuple(a.shape) for a in jax.tree.leaves(args)
                              if hasattr(a, "shape"))[:4]))
             return fn(*args)
+        counted.__name__ = counted.__qualname__ = kind
         return jax.jit(counted)
 
     prog = SessionProgram(
