@@ -47,6 +47,7 @@ def _alias_state(dst: StageState, src: StageState) -> None:
                     if src.params is not None else None)
     dst.loss_sum = 0.0
     dst.token_count = 0
+    dst.saved_fwd = None        # a forward of the old params
 
 
 @dataclasses.dataclass(frozen=True)

@@ -49,8 +49,16 @@ def reference_losses(cfg: ArchConfig, programs: Sequence[StageProgram],
             xs = [b["tokens"]]              # per-stage boundary inputs
             for s in range(S - 1):
                 xs.append(fwd_wire[s](programs[s].fwd(params[s], xs[-1])))
-            loss, gx, gp = programs[S - 1].bwd(params[S - 1], xs[-1],
-                                               b["labels"])
+            last = programs[S - 1]
+            if last.fwd_save is not None:
+                # the last hop as a peer runs it: the loss forward keeps
+                # its residuals and the backward consumes them
+                loss, saved = last.fwd_save(params[S - 1], xs[-1],
+                                            b["labels"])
+                gx, gp = last.bwd_saved(params[S - 1], xs[-1], b["labels"],
+                                        saved)
+            else:
+                loss, gx, gp = last.bwd(params[S - 1], xs[-1], b["labels"])
             grads[S - 1] = jax.tree.map(jnp.add, grads[S - 1], gp)
             for s in range(S - 2, 0, -1):
                 gx, gp = programs[s].bwd(params[s], xs[s],

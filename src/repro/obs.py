@@ -13,6 +13,9 @@ Names are ``repro.<layer>.<call>``:
 
 * ``exec.run_fwd``, ``exec.run_bwd``, ``exec.accumulate``,
   ``exec.adopt_step`` — executor calls (``repro.runtime``);
+* ``exec.bwd_saved`` — inside a last stage's ``exec.run_bwd``, the
+  backward that consumes its forward's residuals instead of running the
+  forward again (``repro.runtime.numeric``);
 * ``wire.fwd``, ``wire.bwd`` — the wire codec step
   (``repro.runtime.base.wire_fwd_codec``/``wire_bwd_codec``);
 * ``hop.fwd``, ``hop.bwd`` — one trainer hop's numeric work
@@ -22,7 +25,10 @@ Names are ``repro.<layer>.<call>``:
 
 Counters are one process-wide store: ``count(key, n)``, ``counters()``
 and ``reset()``.  The runtime's retrace counter
-(``repro.runtime.numeric.record_trace``) keeps its counts here.
+(``repro.runtime.numeric.record_trace``) keeps its counts here, and so
+does the last stage's backward: ``("exec.bwd_saved", stage)`` where it
+consumed its forward's residuals, ``("exec.bwd_recomputed", stage)``
+where it ran the forward again.
 
 This module imports nothing of ``repro``, so every layer can use it.
 """

@@ -41,6 +41,7 @@ all its peers resumes from the latest completed step instead of step 0
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any, Callable, Hashable, Iterable, Optional, Protocol, \
     runtime_checkable
@@ -106,6 +107,11 @@ class StageState:
         # span backends: global stage id -> per-stage StageState; the
         # outer object then carries no tensors of its own
         self.per_stage = per_stage
+        # a last stage's pending loss forward (``SavedForward``): the
+        # residuals its backward consumes.  Not a slot: it never travels,
+        # and every state install drops it (``reset_progress``, and the
+        # zero-copy alias of ``repro.core.peer``)
+        self.saved_fwd: Optional[SavedForward] = None
 
     # ------------------------------------------------------------- slots
     def slot(self, name: str) -> dict[Hashable, Tree]:
@@ -165,14 +171,30 @@ class StageState:
         self.token_count = 0
 
     def reset_progress(self):
-        """Fresh accumulator (zeros shaped/placed like ``params``) and
-        cleared loss/token counters — the tail of every state install
-        (restore, adopt_step): a download or step never imports grads.
+        """Fresh accumulator (zeros shaped/placed like ``params``),
+        cleared loss/token counters and no pending saved forward — the
+        tail of every state install (restore, adopt_step): a download or
+        step never imports grads, and a forward of the old params is
+        never a backward's.
         Non-core slots (e.g. serving KV) are untouched: adopting an
         optimizer step must not evict live sessions."""
         self.grad_acc = jax.tree.map(jnp.zeros_like, self.params)
         self.loss_sum = 0.0
         self.token_count = 0
+        self.saved_fwd = None
+
+
+@dataclasses.dataclass
+class SavedForward:
+    """One last-stage loss forward whose backward has not run yet: the
+    call's own ``inp``, ``labels`` and ``params`` objects (a backward
+    consumes ``saved`` only for these same objects), the forward's loss,
+    and the pullback's residuals (``StageProgram.fwd_save``)."""
+    inp: Tree
+    labels: Any
+    params: Tree
+    loss: Any
+    saved: list
 
 
 @runtime_checkable
@@ -235,14 +257,20 @@ class StageExecutor(Protocol):
                 labels: Optional[jax.Array] = None) -> Tree:
         """Span forward from the boundary input.  A span covering the
         last stage returns the token-sum loss; others return the
-        outbound wire tensor."""
+        outbound wire tensor.  A single-stage last stage whose programs
+        offer ``fwd_save`` (``NumericExecutor``, decoder-only) also
+        keeps the pullback's residuals on ``state.saved_fwd``, one
+        forward's at most, for the ``run_bwd`` that follows."""
         ...
 
     def run_bwd(self, state: StageState, inp: Tree,
                 dy: Optional[Tree] = None,
                 labels: Optional[jax.Array] = None
                 ) -> tuple[Optional[float], Optional[Tree], Tree]:
-        """Span backward (recomputes forward from ``inp``, App. A).
+        """Span backward.  It recomputes the forward from ``inp`` (App.
+        A), unless ``state.saved_fwd`` holds the residuals of a
+        ``run_fwd`` on these same ``inp``, ``labels`` and params objects:
+        then it consumes them and returns that forward's loss.
         Returns ``(loss, gx, gp)``; ``loss`` only when the span covers
         the last stage, ``gx`` None when it starts at 0.  Single-stage
         backends return ``gp`` as the stage's param tree; span backends

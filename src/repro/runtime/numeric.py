@@ -28,9 +28,9 @@ import jax.numpy as jnp
 from repro import obs
 from repro.compression import codecs
 from repro.models.config import ArchConfig
-from repro.runtime.base import StageState, exec_span, fold_into, \
-    host_snapshot, install_snapshot, single_stage, slot_export, \
-    slot_install, wire_bwd_codec, wire_fwd_codec
+from repro.runtime.base import SavedForward, StageState, exec_span, \
+    fold_into, host_snapshot, install_snapshot, single_stage, \
+    slot_export, slot_install, wire_bwd_codec, wire_fwd_codec
 from repro.models.stage_plan import get_stage_plan
 from repro.runtime.stage_model import (SpanProgram, StageProgram,
                                        build_span_program,
@@ -197,19 +197,37 @@ class NumericExecutor:
     @exec_span
     def run_fwd(self, state: StageState, inp: Tree,
                 labels: Optional[jax.Array] = None) -> Tree:
-        if self.stage == self.n_stages - 1:
+        if self.stage != self.n_stages - 1:
+            return self.prog.fwd(state.params, inp)
+        if self.prog.fwd_save is None:
             return self.prog.fwd(state.params, inp, labels)
-        return self.prog.fwd(state.params, inp)
+        state.saved_fwd = None          # one pending forward per state
+        loss, saved = self.prog.fwd_save(state.params, inp, labels)
+        state.saved_fwd = SavedForward(inp, labels, state.params, loss,
+                                       saved)
+        return loss
 
     @exec_span
     def run_bwd(self, state: StageState, inp: Tree,
                 dy: Optional[Tree] = None,
                 labels: Optional[jax.Array] = None):
-        if self.stage == self.n_stages - 1:
-            loss, gx, gp = self.prog.bwd(state.params, inp, labels)
-            return loss, gx, gp
-        gx, gp = self.prog.bwd(state.params, inp, dy)
-        return None, gx, gp
+        if self.stage != self.n_stages - 1:
+            gx, gp = self.prog.bwd(state.params, inp, dy)
+            return None, gx, gp
+        hit = state.saved_fwd
+        if hit is not None and hit.inp is inp and hit.labels is labels \
+                and hit.params is state.params:
+            state.saved_fwd = None
+            with obs.span("exec.bwd_saved", stage=self.stage):
+                gx, gp = self.prog.bwd_saved(state.params, inp, labels,
+                                             hit.saved)
+            obs.count(("exec.bwd_saved", self.stage))
+            return hit.loss, gx, gp
+        # a miss: the recompute program, whose loss and gradients a mesh
+        # or pipeline executor's backward of this stage gives bit for bit
+        # (the pair's gradients are the same; its loss is the forward's)
+        obs.count(("exec.bwd_recomputed", self.stage))
+        return self.prog.bwd(state.params, inp, labels)
 
     # ------------------------------------------------- dispatch / collect
     def dispatch_fwd(self, state: StageState, inp: Tree,

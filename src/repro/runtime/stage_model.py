@@ -33,6 +33,7 @@ one set of stage numerics.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Optional
 
 import jax
@@ -59,9 +60,18 @@ class StageProgram:
     fwd: Callable                 # jitted
     bwd: Callable                 # jitted
     fwd_flops_per_token: float
-    bwd_flops_per_token: float    # includes checkpoint recompute
+    # includes checkpoint recompute, and the forward run again wherever
+    # ``bwd`` runs: every stage but a last stage whose backward consumes
+    # the residuals ``fwd_save`` kept (``bwd_saved``)
+    bwd_flops_per_token: float
     fwd_fn: Optional[Callable] = None   # unjitted (mesh backends re-jit
     bwd_fn: Optional[Callable] = None   # with their own shardings)
+    # decoder-only last stage only (jitted), else None:
+    # fwd_save(params, inp, labels) -> (loss, saved) keeps the pullback's
+    # residuals; bwd_saved(params, inp, labels, saved) -> (gx, gp)
+    # consumes them (donated) instead of running the forward again
+    fwd_save: Optional[Callable] = None
+    bwd_saved: Optional[Callable] = None
 
 
 @dataclasses.dataclass
@@ -90,8 +100,8 @@ class SpanProgram:
         return range(*self.span)
 
 
-def _traced(fn: Callable, hook: Optional[Callable], stage, kind: str
-            ) -> Callable:
+def _traced(fn: Callable, hook: Optional[Callable], stage, kind: str,
+            donate_argnums: tuple = ()) -> Callable:
     """Jit ``fn``; if ``hook`` is given, call it once per XLA trace (the
     body side effect runs at trace time only) with the argument shapes —
     the runtime layer's retrace counter hangs off this.  ``stage`` is an
@@ -105,7 +115,7 @@ def _traced(fn: Callable, hook: Optional[Callable], stage, kind: str
         return fn(*args)
     counted.__name__ = counted.__qualname__ = (
         ("span_" if isinstance(stage, tuple) else "stage_") + kind)
-    return jax.jit(counted)
+    return jax.jit(counted, donate_argnums=donate_argnums)
 
 
 def _stage_runs(cfg: ArchConfig, s: int, n_stages: int):
@@ -221,6 +231,58 @@ def _head_loss(cfg: ArchConfig, params: Tree, x, labels):
     gold = jnp.take_along_axis(logits, labels[..., None],
                                axis=-1)[..., 0]
     return jnp.sum(lse - gold)
+
+
+def _saved_pair(loss_fn: Callable, with_gx: bool
+                ) -> tuple[Callable, Callable]:
+    """The last stage's loss forward that keeps its pullback, and the
+    backward that consumes it (``StageProgram.fwd_save``/``bwd_saved``).
+
+    ``fwd_save`` takes ``jax.vjp`` of ``loss_fn`` with respect to the
+    params (and the boundary input when ``with_gx``) and returns the loss
+    and the pullback's residual leaves, minus those that are the
+    program's own arguments: returned from the program, an argument
+    would come back as a fresh copy (the stage's float32 params, on
+    every microbatch).  Each trace records, per argument shapes, the
+    pullback's treedef and where the argument leaves sat in it;
+    ``bwd_saved`` puts the arguments it is given back in those places
+    and applies the pullback to a cotangent of 1, for the gradients
+    ``jax.grad`` of ``loss_fn`` gives."""
+    layouts: dict = {}
+
+    def key(*args):
+        return tuple((a.shape, a.dtype) for a in jax.tree.leaves(args))
+
+    def fwd_save(params, inp, labels):
+        if with_gx:
+            loss, pullback = jax.vjp(lambda p, x: loss_fn(p, x, labels),
+                                     params, inp)
+        else:
+            loss, pullback = jax.vjp(lambda p: loss_fn(p, inp, labels),
+                                     params)
+        leaves, treedef = jax.tree.flatten(pullback)
+        args = {id(a): i for i, a in
+                enumerate(jax.tree.leaves((params, inp, labels)))}
+        at = {j: args[id(r)] for j, r in enumerate(leaves) if id(r) in args}
+        layouts[key(params, inp, labels)] = (treedef, at, loss.shape,
+                                             loss.dtype)
+        return loss, [r for j, r in enumerate(leaves) if j not in at]
+
+    def bwd_saved(params, inp, labels, saved):
+        treedef, at, shape, dtype = layouts[key(params, inp, labels)]
+        args = jax.tree.leaves((params, inp, labels))
+        rest = iter(saved)
+        pullback = jax.tree.unflatten(treedef, [
+            args[at[j]] if j in at else next(rest)
+            for j in range(treedef.num_leaves)])
+        grads = pullback(jnp.ones(shape, dtype))
+        if with_gx:
+            gp, gx = grads
+            return gx, gp
+        (gp,) = grads
+        return None, gp
+
+    return fwd_save, bwd_saved
 
 
 def _stage_fwd_flops(cfg: ArchConfig, s: int, n_stages: int, seq_len: int,
@@ -482,13 +544,27 @@ def build_stage_programs(cfg: ArchConfig, n_stages: int, seq_len: int,
                 return gx, gp
         fwd_j = _traced(fwd, trace_hook, s, "fwd")
         bwd_j = _traced(bwd, trace_hook, s, "bwd")
+        pair = {}
+        if is_last:
+            # the saved pair runs the head again in its backward: kept,
+            # its f32 logits (tokens x vocab) and bf16 weight cast would
+            # hold device memory from the forward to the backward's end
+            def saved_loss(params, inp, labels, _fwd=stage_fwd):
+                return jax.checkpoint(functools.partial(_head_loss, cfg))(
+                    params, _fwd(params, inp), labels)
+
+            fwd_save, bwd_saved = _saved_pair(saved_loss, not is_first)
+            pair = dict(
+                fwd_save=_traced(fwd_save, trace_hook, s, "fwd_save"),
+                bwd_saved=_traced(bwd_saved, trace_hook, s, "bwd_saved",
+                                  donate_argnums=(3,)))
 
         fwd_f = _stage_fwd_flops(cfg, s, n_stages, seq_len, comp, learned)
         programs.append(StageProgram(
             stage=s, n_stages=n_stages, specs=specs, fwd=fwd_j, bwd=bwd_j,
             fwd_flops_per_token=fwd_f,
             bwd_flops_per_token=3.0 * fwd_f,   # recompute + 2x backward
-            fwd_fn=fwd, bwd_fn=bwd,
+            fwd_fn=fwd, bwd_fn=bwd, **pair,
         ))
     return programs
 

@@ -186,7 +186,10 @@ def test_span_is_a_profiler_annotation():
 
 def test_compile_stats_keep_their_counts_in_the_store():
     """The retrace scenario of the shared compile cache: 4 peers x 2
-    stages trace one fwd and one bwd per stage, a second runner none."""
+    stages trace one fwd and one bwd of stage 0 and one fwd_save and one
+    bwd_saved of the last stage (every last-stage backward of these runs
+    consumes its forward's residuals, so its recompute bwd is never
+    traced), a second runner none."""
     reset_compile_stats()
     cfg = tiny_dense_config()
     scfg = SwarmConfig(n_stages=2, microbatch_size=MB, seq_len=SEQ,
@@ -202,8 +205,11 @@ def test_compile_stats_keep_their_counts_in_the_store():
         assert st["traces"] == 4, st["per_key"]
         assert all(v == 1 for v in st["per_key"].values())
         assert sorted(k[-2] for k in st["per_key"]) == \
-            ["bwd", "bwd", "fwd", "fwd"]
-    assert sum(obs.counters().values()) == 4
+            ["bwd", "bwd_saved", "fwd", "fwd_save"]
+    # the store holds the trace counts and the two runs' 2 + 2 saved
+    # last-stage backwards, nothing else
+    assert obs.counters() == {**{("xla_trace", k): 1 for k in st["per_key"]},
+                              ("exec.bwd_saved", 1): 4}
     reset_compile_stats()
     assert compile_stats() == {"traces": 0, "per_key": {}}
 

@@ -129,12 +129,18 @@ def test_compile_cache_one_trace_per_stage_shape_and_codec():
     st = compile_stats()
     assert st["per_key"], "no traces recorded"
     assert all(v == 1 for v in st["per_key"].values()), st["per_key"]
-    # one fwd + one bwd per stage = 4 jits total, not peers x stages x 2
-    assert st["traces"] == 4, st["per_key"]
+    # stage 0 traces one fwd and one bwd; the last stage one fwd_save
+    # and one bwd_saved, and its recompute bwd once because the seed-0
+    # run's interleaved trainers make one last-stage backward miss its
+    # forward's residuals: 5 jits total, not peers x stages x 2
+    assert st["traces"] == 5, st["per_key"]
+    assert sorted(k[-3:-1] for k in st["per_key"]) == [
+        (0, "bwd"), (0, "fwd"), (1, "bwd"), (1, "bwd_saved"),
+        (1, "fwd_save")]
     r2 = SwarmRunner(cfg, scfg, opt, numeric=True, seed=1)
     r2.build(peers_per_stage=4)
     r2.run(until=1e6)
-    assert compile_stats()["traces"] == 4       # zero new traces
+    assert compile_stats()["traces"] == 5       # zero new traces
 
 
 def test_codec_mode_is_part_of_the_cache_key():
