@@ -4,7 +4,8 @@ One sequential peer per stage, the same data order and the same
 parameter init as the runner: every churn, runtime, span and chip-smoke
 equivalence check compares ``SwarmRunner`` against
 :func:`reference_losses`.  The accumulation and token-weighted averaging
-here must stay in lockstep with ``SwarmRunner._ar_plan``.
+here must stay in lockstep with ``SwarmRunner._ar_plan``: both take the
+optimizer step through :func:`repro.optim.adamw.mean_step`.
 """
 from __future__ import annotations
 
@@ -15,7 +16,7 @@ import jax.numpy as jnp
 
 from repro.data.synthetic import SyntheticLM
 from repro.models.config import ArchConfig
-from repro.optim.adamw import Optimizer
+from repro.optim.adamw import Optimizer, mean_step
 from repro.runtime import StageExecutor, StageProgram, init_stage_params
 
 
@@ -36,6 +37,7 @@ def reference_losses(cfg: ArchConfig, programs: Sequence[StageProgram],
     raw = [lambda t: t] * S
     fwd_wire = [e.wire_fwd for e in executors] if executors else raw
     bwd_wire = [e.wire_bwd for e in executors] if executors else raw
+    step = mean_step(opt)
     params = init_stage_params(programs, jax.random.PRNGKey(seed))
     opt_states = [opt.init(p) for p in params]
     ds = SyntheticLM(cfg.vocab_size, seq, mb, seed=data_seed)
@@ -70,8 +72,6 @@ def reference_losses(cfg: ArchConfig, programs: Sequence[StageProgram],
             tok += mb * seq
         losses.append(loss_sum / tok)
         for s in range(S):
-            gm = jax.tree.map(lambda g: g / tok, grads[s])
-            upd, opt_states[s] = opt.update(gm, opt_states[s], params[s])
-            params[s] = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
-                                     params[s], upd)
+            params[s], opt_states[s] = step(
+                [grads[s]], float(tok), params[s], opt_states[s])
     return losses

@@ -48,7 +48,7 @@ from repro.core.faults import TraceEvent
 from repro.models.config import ArchConfig
 from repro.models import flops as F
 from repro.models.stage_plan import StagePlan, get_stage_plan
-from repro.optim.adamw import Optimizer
+from repro.optim.adamw import Optimizer, mean_step
 from repro.runtime import StageExecutor, StageProgram, \
     build_numeric_executors, init_stage_params
 
@@ -193,6 +193,7 @@ class SwarmRunner:
             from repro.optim.dpu import delayed_parameter_updates
             optimizer = delayed_parameter_updates(optimizer, delay=1)
         self.optimizer = optimizer
+        self._mean_step = mean_step(optimizer)
         self.overlap = bool(scfg.overlap)
         self.numeric = numeric
         self.sim = Sim()
@@ -477,7 +478,8 @@ class SwarmRunner:
         with failed or migrated peers (App. A)."""
         if self.stopped or self._dispatch_paused:
             return None
-        nxt = self.ledger.next_index()
+        with obs.span("ctl.ledger"):
+            nxt = self.ledger.next_index()
         if nxt is None:
             return None
         idx, attempt = nxt
@@ -504,7 +506,8 @@ class SwarmRunner:
         self._inflight -= 1
         # the ledger re-queues the index iff some stage still lacks it
         # (failed attempt, or a holder died mid-flight)
-        self.ledger.settle(mb.index)
+        with obs.span("ctl.ledger", mb=mb.index):
+            self.ledger.settle(mb.index)
         if ok:
             self._samples_done_total += mb.size
             self.metrics["throughput_t"].append(self.sim.now)
@@ -598,7 +601,9 @@ class SwarmRunner:
         last = self.n_stages - 1
         any_folded = False
         for s in stages:
-            if not self.ledger.record(s, mb.index, peer.id):
+            with obs.span("ctl.ledger", mb=mb.index, stage=s):
+                fresh = self.ledger.record(s, mb.index, peer.id)
+            if not fresh:
                 continue
             if self.record_accumulation:
                 self.ledger_log.append(
@@ -756,19 +761,12 @@ class SwarmRunner:
                 # mixes numeric, mesh-backed, and span peers freely
                 total_tokens = sum(p.state.stage_view(s).token_count
                                    for p in group)
-                gsum = group[0].executor.export_grads(group[0].state,
-                                                      stage=s)
-                for p in group[1:]:
-                    gsum = jax.tree.map(
-                        lambda a, b: a + b, gsum,
-                        p.executor.export_grads(p.state, stage=s))
-                gmean = jax.tree.map(lambda g: g / max(total_tokens, 1),
-                                     gsum)
+                grads = [p.executor.export_grads(p.state, stage=s)
+                         for p in group]
                 params, opt = group[0].executor.export_state(
                     group[0].state, stage=s)
-                updates, new_opt = self.optimizer.update(gmean, opt, params)
-                new_params = jax.tree.map(
-                    lambda p, u: p + u.astype(p.dtype), params, updates)
+                new_params, new_opt = self._mean_step(
+                    grads, float(max(total_tokens, 1)), params, opt)
                 loss_sum = sum(p.state.stage_view(s).loss_sum
                                for p in group)
                 if s == self.n_stages - 1 and total_tokens:

@@ -87,21 +87,23 @@ class Trainer:
     def _pick(self, stage: int, span: Optional[range] = None):
         """Choose a live peer whose span STARTS at ``stage`` (optionally
         covering exactly ``span`` — the backward re-route constraint),
-        or None when unavailable."""
-        self._maybe_refresh()
-        peer_id = self.wiring.choose_server(stage)
-        if peer_id is None:
-            return None
-        peer = self.swarm.peers.get(peer_id)
-        if peer is None or not peer.alive or not peer.serving \
-                or peer.stage != stage:
-            self.wiring.ban_server(peer_id)
-            return None
-        if span is not None and peer.stages != span:
-            # a healthy peer with a different span: not bannable, just
-            # unusable for this cotangent — the caller retries/fails
-            return None
-        return peer
+        or None when unavailable.  Runs in the ``repro.ctl.route`` span."""
+        with obs.span("ctl.route", stage=stage):
+            self._maybe_refresh()
+            peer_id = self.wiring.choose_server(stage)
+            if peer_id is None:
+                return None
+            peer = self.swarm.peers.get(peer_id)
+            if peer is None or not peer.alive or not peer.serving \
+                    or peer.stage != stage:
+                self.wiring.ban_server(peer_id)
+                return None
+            if span is not None and peer.stages != span:
+                # a healthy peer with a different span: not bannable,
+                # just unusable for this cotangent — the caller
+                # retries/fails
+                return None
+            return peer
 
     def _boundary_bytes(self, mb: Microbatch,
                         boundary: Optional[int] = None) -> float:

@@ -2,7 +2,10 @@
 
 Training paths are chunkwise-parallel (memory O(chunk), FLOPs linear in T);
 decode paths are O(1)-state single-step recurrences — this is what makes the
-``long_500k`` shape servable for the ssm/hybrid architectures (DESIGN.md §5).
+``long_500k`` shape servable for the ssm/hybrid architectures.  The mLSTM
+chunk scan and the sLSTM time recurrence (forward and backward) run inside
+the named scopes ``repro.ssm.mlstm`` and ``repro.ssm.slstm``, which the
+compiled ops carry in their metadata (``repro.obs``).
 """
 from __future__ import annotations
 
@@ -231,8 +234,9 @@ def apply_mlstm(cfg: ArchConfig, p: Tree, x: jax.Array,
 
     C0 = jnp.zeros((B, H, hd, hd + 1), jnp.float32)
     m0 = jnp.zeros((B, H), jnp.float32)
-    (C_f, m_f), ys = jax.lax.scan(jax.checkpoint(chunk_step), (C0, m0),
-                                  (qc, kc, vc, lic, lfc))
+    with jax.named_scope("repro.ssm.mlstm"):
+        (C_f, m_f), ys = jax.lax.scan(jax.checkpoint(chunk_step), (C0, m0),
+                                      (qc, kc, vc, lic, lfc))
     y = ys.swapaxes(0, 1).reshape(B, T, H, hd + 1)
     num, den = y[..., :hd], y[..., hd:]
     y = num / jnp.maximum(jnp.abs(den), 1.0)
@@ -293,11 +297,11 @@ def slstm_specs(cfg: ArchConfig) -> Tree:
     }
 
 
-def _slstm_cell(p_r, p_b, hd, wx_t, state):
-    """One sLSTM step. wx_t [B,H,4hd]; state (c,n,h,m) each [B,H,hd]."""
-    c, n, h, m = state
-    pre = wx_t + jnp.einsum("bhk,hkg->bhg", h, p_r) + p_b
-    zi, ii, fi, oi = jnp.split(pre.astype(jnp.float32), 4, axis=-1)
+def _slstm_gates(pre, c, n, m):
+    """The sLSTM's pointwise step from its float32 pre-activations
+    [B,H,4hd]; c, n, m [B,H,hd] float32.  Returns (c, n, h, m), h in
+    float32."""
+    zi, ii, fi, oi = jnp.split(pre, 4, axis=-1)
     z = jnp.tanh(zi)
     o = jax.nn.sigmoid(oi)
     logf = jax.nn.log_sigmoid(fi)
@@ -307,7 +311,88 @@ def _slstm_cell(p_r, p_b, hd, wx_t, state):
     c_new = fp * c + ip * z
     n_new = fp * n + ip
     h_new = o * c_new / jnp.maximum(n_new, 1.0)
-    return (c_new, n_new, h_new.astype(wx_t.dtype), m_new)
+    return c_new, n_new, h_new, m_new
+
+
+def _slstm_pre(r, b, wx_t, h):
+    """Float32 pre-activations of one step; the recurrent product takes
+    ``h`` and ``r`` in the compute dtype."""
+    rh = jnp.einsum("...hk,hkg->...hg", h, r.astype(h.dtype))
+    return (wx_t.astype(jnp.float32) + rh.astype(jnp.float32)
+            + b.astype(jnp.float32))
+
+
+def _slstm_cell(r, b, wx_t, state):
+    """One sLSTM step, shared by training and decode.  wx_t [B,H,4hd];
+    state (c, n, h, m) each [B,H,hd], h in the compute dtype."""
+    c, n, h, m = state
+    c, n, h_new, m = _slstm_gates(_slstm_pre(r, b, wx_t, h), c, n, m)
+    return c, n, h_new.astype(h.dtype), m
+
+
+def _slstm_run(r, b, wx):
+    """The forward recurrence over wx [T,B,H,4hd]: the final state and
+    every step's state, each stacked [T,B,H,hd]."""
+    _, B, H, g = wx.shape
+    z = jnp.zeros((B, H, g // 4), jnp.float32)
+    state0 = (z, z, z.astype(wx.dtype), z)
+    r = r.astype(wx.dtype)              # once, not on every step
+
+    def step(state, wx_t):
+        new = _slstm_cell(r, b, wx_t, state)
+        return new, new
+
+    return jax.lax.scan(step, state0, wx)
+
+
+@jax.custom_vjp
+def _slstm_scan(r, b, wx):
+    """(final state, hs [T,B,H,hd]) of the recurrence over wx [T,B,H,4hd].
+
+    The backward is written out so that the gradients of the recurrent
+    weights ``r`` and bias ``b`` are summed over time in float32 outside
+    the loop, as one contraction of the step inputs with the
+    pre-activation cotangents.  Left to autodiff, the loop carries their
+    sum in the dtype ``r`` was cast to, so a bfloat16 model adds 2,048
+    steps' terms in bfloat16."""
+    return _slstm_scan_fwd(r, b, wx)[0]
+
+
+def _slstm_scan_fwd(r, b, wx):
+    with jax.named_scope("repro.ssm.slstm"):
+        final, states = _slstm_run(r, b, wx)
+    return (final, states[2]), (r, b, wx, states)
+
+
+def _slstm_scan_bwd(res, cts):
+    r, b, wx, states = res
+    (dc, dn, dh, dm), dhs = cts
+    f32, cd = jnp.float32, wx.dtype
+    with jax.named_scope("repro.ssm.slstm"):
+        # each step's input state: zeros, then the states the forward
+        # left behind; the pre-activations again, all steps at once
+        c, n, h, m = (jnp.concatenate([jnp.zeros_like(s[:1]), s[:-1]])
+                      for s in states)
+        pre = _slstm_pre(r, b, wx, h)
+        rc = r.astype(cd)
+
+        def step(carry, xs):
+            dc, dn, dh, dm = carry
+            pre_t, c_t, n_t, m_t, dh_out = xs
+            _, pull = jax.vjp(_slstm_gates, pre_t, c_t, n_t, m_t)
+            dpre, dc, dn, dm = pull((dc, dn, dh + dh_out, dm))
+            dh = jnp.einsum("bhg,hkg->bhk", dpre.astype(cd), rc)
+            return (dc, dn, dh.astype(f32), dm), dpre
+
+        _, dpre = jax.lax.scan(
+            step, (dc, dn, dh.astype(f32), dm),
+            (pre, c, n, m, dhs.astype(f32)), reverse=True)
+        dr = jnp.einsum("tbhk,tbhg->hkg", h.astype(f32), dpre)
+        db = jnp.sum(dpre, axis=(0, 1))
+    return dr.astype(r.dtype), db.astype(b.dtype), dpre.astype(cd)
+
+
+_slstm_scan.defvjp(_slstm_scan_fwd, _slstm_scan_bwd)
 
 
 def apply_slstm(cfg: ArchConfig, p: Tree, x: jax.Array,
@@ -315,18 +400,8 @@ def apply_slstm(cfg: ArchConfig, p: Tree, x: jax.Array,
     """Sequential sLSTM (memory mixing forbids parallel scan). x [B,T,d]."""
     cd = x.dtype
     B, T, d = x.shape
-    H = cfg.n_heads
-    hd = d // H
     wx = jnp.einsum("btd,dhg->bthg", x, p["w"].astype(cd))
-    r, b = p["r"].astype(cd), p["b"].astype(cd)
-
-    def step(state, wx_t):
-        new = _slstm_cell(r, b, hd, wx_t, state)
-        return new, new[2]
-
-    z = jnp.zeros((B, H, hd), jnp.float32)
-    state0 = (z, z, jnp.zeros((B, H, hd), cd), z)
-    (c, n, h, m), hs = jax.lax.scan(step, state0, wx.swapaxes(0, 1))
+    (c, n, h, m), hs = _slstm_scan(p["r"], p["b"], wx.swapaxes(0, 1))
     y = hs.swapaxes(0, 1).reshape(B, T, d)
     out = y @ p["wo"].astype(cd)
     if return_state:
@@ -346,11 +421,8 @@ def slstm_cache_specs(cfg: ArchConfig, batch: int) -> Tree:
 
 def apply_slstm_decode(cfg: ArchConfig, p: Tree, x: jax.Array, cache: Tree):
     cd = x.dtype
-    H = cfg.n_heads
-    hd = cfg.d_model // H
     wx = jnp.einsum("bd,dhg->bhg", x[:, 0], p["w"].astype(cd))
     state = (cache["c"], cache["n"], cache["h"], cache["m"])
-    c, n, h, m = _slstm_cell(p["r"].astype(cd), p["b"].astype(cd), hd, wx,
-                             state)
+    c, n, h, m = _slstm_cell(p["r"], p["b"], wx, state)
     y = h.reshape(x.shape[0], -1) @ p["wo"].astype(cd)
     return y[:, None], {"c": c, "n": n, "h": h, "m": m}

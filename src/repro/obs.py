@@ -21,7 +21,23 @@ Names are ``repro.<layer>.<call>``:
 * ``hop.fwd``, ``hop.bwd`` — one trainer hop's numeric work
   (``repro.core.trainer``), args ``mb``, ``stage``, ``peer``;
 * ``swarm.barrier`` — the All-Reduce barrier's math and install
-  (``repro.core.swarm``), arg ``step``.
+  (``repro.core.swarm``), arg ``step``;
+* ``ctl.route`` — a trainer's choice of a hop's peer through the
+  stochastic wiring (``repro.core.trainer.Trainer._pick``), arg
+  ``stage``; it runs in the trainer's own turn of the sim, between hops,
+  not inside a ``hop.*`` span;
+* ``ctl.ledger`` — the microbatch ledger's work: issue
+  (``SwarmRunner.next_microbatch``), commit (``SwarmRunner.accumulate``,
+  inside a backward ``hop.bwd``; args ``mb``, ``stage``) and settle
+  (``SwarmRunner.microbatch_done``, arg ``mb``).
+
+Device work is named with ``jax.named_scope`` where a layer's own loop
+is the thing to time: ``repro.ssm.mlstm`` (the mLSTM chunk scan) and
+``repro.ssm.slstm`` (the sLSTM time recurrence, its forward and its
+backward) in ``repro.models.ssm``.  Every compiled op of those loops
+carries the scope in its ``op_name`` metadata (a backward pass's ops
+under a ``transpose(...)`` level of the name stack), which a TPU
+profiler trace keeps as the op's ``tf_op`` stat.
 
 Counters are one process-wide store: ``count(key, n)``, ``counters()``
 and ``reset()``.  The runtime's retrace counter

@@ -16,6 +16,24 @@ class Optimizer:
     update: Callable[[Tree, Tree, Tree], tuple[Tree, Tree]]
 
 
+def mean_step(opt: Optimizer) -> Callable:
+    """``step(grad_trees, tokens, params, state) -> (params, state)``,
+    jitted: sum the gradient trees (one per peer of a stage), divide by
+    the step's token count, apply ``opt`` and add the update in each
+    parameter's dtype.  One program per tree shape, where the same math
+    run leaf by leaf dispatched some ten device ops per parameter."""
+    def step(grad_trees, tokens, params, state):
+        g = grad_trees[0]
+        for h in grad_trees[1:]:
+            g = jax.tree.map(lambda a, b: a + b, g, h)
+        g = jax.tree.map(lambda x: x / tokens, g)
+        updates, state = opt.update(g, state, params)
+        params = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
+                              params, updates)
+        return params, state
+    return jax.jit(step)
+
+
 def _zeros_like_f32(t: Tree) -> Tree:
     return jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), t)
 

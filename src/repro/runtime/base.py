@@ -497,11 +497,21 @@ def _int8_roundtrip_tree(tree: Tree, quant_block: int,
     ``use_kernel`` routes through the fused single-launch Pallas round
     trip (same codes)."""
     if use_kernel:
-        from repro.kernels.boundary.ops import int8_roundtrip
-        rt = lambda a: int8_roundtrip(a, quant_block, quant_block, True)
-    else:
-        from repro.compression.quant8 import _roundtrip
-        rt = lambda a: _roundtrip(a, quant_block)
+        return _kernel_roundtrip_tree(tree, quant_block)
+    from repro.compression.quant8 import _roundtrip
+    return _map_floating(lambda a: _roundtrip(a, quant_block), tree)
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def _kernel_roundtrip_tree(tree: Tree, quant_block: int) -> Tree:
+    """The Pallas round trip, jitted: one program per payload shape,
+    where an eager call traced and lowered the kernel again each time."""
+    from repro.kernels.boundary.ops import int8_roundtrip
+    return _map_floating(
+        lambda a: int8_roundtrip(a, quant_block, quant_block, True), tree)
+
+
+def _map_floating(rt: Callable, tree: Tree) -> Tree:
     return jax.tree.map(
         lambda a: rt(a)
         if jnp.issubdtype(jnp.asarray(a).dtype, jnp.floating) else a,

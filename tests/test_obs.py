@@ -164,6 +164,31 @@ def test_barrier_span_holds_the_adopts(swarm_trace):
     assert all(any(_inside(a, b) for b in barriers) for a in adopts)
 
 
+def test_control_spans_name_route_and_ledger(swarm_trace):
+    spans = _by_name(swarm_trace)
+    hops = spans["repro.hop.fwd"] + spans["repro.hop.bwd"]
+    # a peer is chosen for every forward hop, between hops; with no peer
+    # lost, each backward hop returns to its forward's peer unchosen
+    routes = spans["repro.ctl.route"]
+    assert len(routes) == len(spans["repro.hop.fwd"])
+    assert {r.stats["stage"] for r in routes} == {0, 1}
+    assert not any(_inside(r, h) for r in routes for h in hops)
+    # the ledger's commits run inside their stage's backward hop; issue
+    # and settle run outside every hop
+    ledger = spans["repro.ctl.ledger"]
+    commits = [s for s in ledger if "stage" in s.stats]
+    assert len(commits) == 8                # 4 microbatches x 2 stages
+    for s in commits:
+        parents = [h for h in hops if _inside(s, h)]
+        assert [h.name for h in parents] == ["repro.hop.bwd"]
+        assert parents[0].stats["stage"] == s.stats["stage"]
+        assert parents[0].stats["mb"] == s.stats["mb"]
+    rest = [s for s in ledger if "stage" not in s.stats]
+    assert rest and not any(_inside(s, h) for s in rest for h in hops)
+    settled = sorted(s.stats["mb"] for s in rest if "mb" in s.stats)
+    assert settled == [0, 1, 2, 3]
+
+
 def test_counters():
     obs.reset()
     obs.count("a")
